@@ -488,9 +488,9 @@ def bounce_smoke() -> None:
     """PR-6 acceptance smoke (docs/kernels.md): the Pallas dataplane
     kernels are bit-identical to the XLA emulation they replace — the
     double-buffered ``bounce_copy`` against ``staged_copy`` on a ragged
-    payload (exercising the padded-tail DMA path), and ``mediated_cost``
-    must leave the payload untouched while its per-chunk SMEM counters
-    account at least the requested delay iterations."""
+    payload (padded to whole chunks outside the kernel), and
+    ``mediated_cost`` must leave the payload untouched while its SMEM
+    cost totals account at least the requested delay iterations."""
     import jax
     import jax.numpy as jnp
     import numpy as np

@@ -24,7 +24,7 @@ from repro.train import init_state, make_explicit_dp_step
 def main():
     cfg = get_model_config("gemma3-1b", smoke=True)
     model = build_model(cfg)
-    mesh = make_local_mesh()
+    mesh = make_local_mesh(jax.devices())
 
     # The paper's knob: route every dataplane op through the mediation
     # layer ("cord"), raw kernel-bypass ("bypass"), or the socket path.

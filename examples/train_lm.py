@@ -48,7 +48,7 @@ def main():
         jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))))
     print(f"model: {n/1e6:.1f}M params")
 
-    mesh = make_local_mesh()
+    mesh = make_local_mesh(jax.devices())
     dp = Dataplane(DataplaneConfig(mode=args.mode), mesh=mesh)
     run = RunConfig(train=TrainConfig(
         steps=args.steps, learning_rate=3e-3, warmup_steps=30,

@@ -1,11 +1,9 @@
-"""JAX version compatibility shims.
+"""Mesh and shard_map constructors with the repo's fixed defaults.
 
-The framework targets the modern JAX API (``jax.shard_map`` with
-``check_vma``, ``jax.sharding.AxisType``); CI images and some
-accelerator containers still ship 0.4.x where those names live under
-``jax.experimental`` or do not exist.  Every mesh/shard_map construction
-in the repo goes through this module so the rest of the codebase can be
-written once against the new surface.
+Every mesh/shard_map construction in the repo goes through this module:
+meshes get explicit ``Auto`` axis types (GSPMD places the collectives),
+and shard_map bodies run with ``check_vma=False`` because the dataplane
+threads per-rank state whose replication JAX cannot infer.
 """
 
 from __future__ import annotations
@@ -13,44 +11,20 @@ from __future__ import annotations
 from typing import Sequence
 
 import jax
-
-try:  # jax >= 0.5
-    from jax.sharding import AxisType as _AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    _AxisType = None
+from jax.sharding import AxisType
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
               devices=None):
-    """``jax.make_mesh`` with explicit-Auto axis types where supported."""
-    kw = {}
-    if devices is not None:
-        kw["devices"] = devices
-    if _AxisType is not None:
-        kw["axis_types"] = (_AxisType.Auto,) * len(axis_names)
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kw)
+    """``jax.make_mesh`` with explicit-Auto axis types."""
+    kw = {} if devices is None else {"devices": devices}
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(axis_names), **kw)
 
 
-if hasattr(jax, "shard_map"):  # jax >= 0.6
-
-    def shard_map(f, *, mesh, in_specs, out_specs):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-
-else:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
+def shard_map(f, *, mesh, in_specs, out_specs):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
-def pallas_tpu_compiler_params(pltpu, **kwargs):
-    """Build Pallas-TPU compiler params across the 0.4.x→0.5 rename
-    (``TPUCompilerParams`` became ``CompilerParams``)."""
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(**kwargs)
-
-
-__all__ = ["make_mesh", "shard_map", "pallas_tpu_compiler_params"]
+__all__ = ["make_mesh", "shard_map"]

@@ -124,12 +124,15 @@ class Dataplane:
             (p for p in self.policies if isinstance(p, SecurityPolicy)), None)
         self.registry: MRRegistry = (self._security.registry
                                      if self._security else MRRegistry())
-        if self.cfg.emulate_costs:
-            # calibrate the delay primitive NOW (eagerly) — calling it for
-            # the first time under a trace would stage the probe jit.
-            tech.calibrate()
         # The single mediation artifact every path compiles against.
         self.pipeline = build_pipeline(self)
+        if self.cfg.emulate_costs:
+            # calibrate the delay primitives NOW (eagerly) — calling them
+            # for the first time under a trace would stage the probe jit.
+            tech.calibrate()
+            if self.pipeline.pallas:
+                from repro.kernels.dataplane import kernel_calibrate
+                kernel_calibrate()
 
     # ------------------------------------------------------------------
     # introspection

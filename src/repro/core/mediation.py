@@ -303,7 +303,7 @@ class MediationPipeline:
         if self.pallas and (iters or copies):
             from repro.kernels import dataplane as dk
             x, kctrs = dk.mediated_cost(x, dk.rescale_iters(iters), copies)
-            # the per-chunk SMEM cost counters, summed into the tenant
+            # the kernel's SMEM cost totals, landed in the tenant
             # block: what the hardware actually burned/copied
             state = self._kernel_ctr_bump(
                 state, tenant_idx,

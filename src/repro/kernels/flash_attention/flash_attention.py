@@ -28,7 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import compat
 
 NEG_INF = -2.0**30
 
@@ -148,8 +147,7 @@ def flash_attention_fwd(q, k, v, scalars, *, causal: bool = True,
             pltpu.VMEM((qb, 128), jnp.float32),
             pltpu.VMEM((qb, 128), jnp.float32),
         ],
-        compiler_params=compat.pallas_tpu_compiler_params(
-            pltpu,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

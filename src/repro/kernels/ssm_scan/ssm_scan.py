@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import compat
-
 
 def _ssm_kernel(dt_ref, x_ref, a_ref, b_ref, c_ref, h0_ref,
                 y_ref, hf_ref, h_scr, *, chunk: int):
@@ -93,8 +91,7 @@ def ssm_scan_fwd(dt, x, a, b, c, h0, *, chunk: int = 128,
             jax.ShapeDtypeStruct((bsz, di, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((db, n), jnp.float32)],
-        compiler_params=compat.pallas_tpu_compiler_params(
-            pltpu,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(dt, x, a, b, c, h0)

@@ -8,8 +8,6 @@ across pods over DCN, TP kept inside the pod over ICI).
 
 from __future__ import annotations
 
-import jax
-
 from repro.core import compat
 
 
@@ -19,12 +17,13 @@ def make_production_mesh(*, multi_pod: bool = False):
     return compat.make_mesh(shape, axes)
 
 
-def make_local_mesh(n: int | None = None, model: int = 1):
-    """CPU-device mesh for measured runs/tests: (data = n/model, model)."""
-    devs = jax.devices()
-    n = n or len(devs)
-    return compat.make_mesh((n // model, model), ("data", "model"),
-                            devices=devs[:n])
+def make_local_mesh(devices, model: int = 1):
+    """A ``(data = len(devices) / model, model)`` mesh over exactly the
+    given devices — host CPU devices in tests, chips on a TPU host.  The
+    caller names the devices, so no run lands on device 0 by default."""
+    devices = list(devices)
+    return compat.make_mesh((len(devices) // model, model),
+                            ("data", "model"), devices=devices)
 
 
 def mesh_axis_sizes(mesh) -> dict:

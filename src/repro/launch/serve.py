@@ -1,8 +1,21 @@
-"""Serving launcher: batched requests through the Engine.
+"""Serving launcher: batched requests through the Engine on a mediated
+dataplane.
 
-``python -m repro.launch.serve --arch gemma3-1b --requests 8
+``python -m repro.launch.serve [--arch granite-3-2b] [--full]
+[--param-dtype bfloat16] [--mode cord|bypass|socket] [--requests 8]
+[--prompt-lens 6,7,8,9,10] [--kv-len 128] [--tenants default]
 [--scheduler continuous|gang] [--block-size 16] [--n-blocks N]
 [--prefill-chunk 512] [--timeline]``
+
+The engine runs on a :class:`~repro.core.dataplane.Dataplane` of the
+chosen mode with emulated OS costs on, over a one-device mesh, so every
+sharding edge of the model crosses the mediation pipeline (on a TPU the
+Pallas dataplane kernels).  ``--full`` serves the published
+configuration (default: the CPU smoke preset); weights are random from
+``--seed``, held in ``--param-dtype`` (default: the configuration's).
+Request *i* has prompt length ``prompt_lens[i % k]`` and tenant
+``tenants[i % m]``; ``--kv-len`` is each request's cache bound (rounded
+up to a whole number of blocks).
 
 ``--block-size`` switches the continuous engine to the paged KV block
 pool (docs/serving.md); ``--n-blocks`` sizes the pool (0 = the stripe
@@ -29,25 +42,60 @@ elastic.release_thresholds=throttled_pct=10 elastic.sustain=2``.
 """
 
 import argparse
+import dataclasses
 import os
 import time
 
 import jax
 import numpy as np
+from jax.sharding import SingleDeviceSharding
 
 from repro.configs import apply_overrides, get_model_config
-from repro.configs.base import ElasticConfig, ObsConfig, ServeConfig
-from repro.core import CounterTimeline
+from repro.configs.base import (
+    DataplaneConfig,
+    ElasticConfig,
+    ObsConfig,
+    ServeConfig,
+)
+from repro.core import CounterTimeline, Dataplane
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_local_mesh
+from repro.layers.common import dtype_of
 from repro.models import build_model
 from repro.runtime import ServeElasticController
-from repro.serve import Engine, Request, prompt_bucket
+from repro.serve import Engine, Request
 
 
-def main() -> None:
+def _ints(text: str) -> tuple[int, ...]:
+    return tuple(int(v) for v in text.split(",") if v)
+
+
+def _names(text: str) -> tuple[str, ...]:
+    return tuple(v for v in text.split(",") if v)
+
+
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="gemma3-1b")
+    ap.add_argument("--arch", default="granite-3-2b")
+    ap.add_argument("--full", action="store_true",
+                    help="the published configuration (default: the CPU "
+                         "smoke preset of --arch)")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of the random weights and prompts")
+    ap.add_argument("--param-dtype", default=None,
+                    help="dtype the weights are held in (default: the "
+                         "configuration's param_dtype)")
+    ap.add_argument("--mode", default="cord",
+                    choices=("bypass", "cord", "socket"))
     ap.add_argument("--requests", type=int, default=8)
+    ap.add_argument("--prompt-lens", type=_ints, default=(6, 7, 8, 9, 10),
+                    help="comma list; request i gets prompt_lens[i % k]")
+    ap.add_argument("--tenants", type=_names, default=("default",),
+                    help="comma list; request i belongs to tenants[i % m]")
     ap.add_argument("--max-new-tokens", type=int, default=16)
+    ap.add_argument("--kv-len", type=int, default=128,
+                    help="per-request cache bound in tokens (prefill cover "
+                         "+ new tokens + 1 must fit)")
     ap.add_argument("--scheduler", default="continuous",
                     choices=("continuous", "gang"))
     ap.add_argument("--max-batch", type=int, default=4)
@@ -69,17 +117,65 @@ def main() -> None:
                          "crossings (implies --timeline; docs/elasticity.md)")
     ap.add_argument("overrides", nargs="*", default=[],
                     help="elastic.* key=value overrides")
-    args = ap.parse_args()
+    return ap
 
-    cfg = get_model_config(args.arch, smoke=True)
+
+def load_model(args, device):
+    """``(cfg, model, params)`` with seeded random weights on ``device``,
+    held in ``args.param_dtype``.  The layers cast every weight to the
+    activation dtype at use, so weights held at that dtype give the same
+    logits in half the memory of float32."""
+    cfg = get_model_config(args.arch, smoke=not args.full)
+    if args.param_dtype:
+        cfg = dataclasses.replace(cfg, param_dtype=args.param_dtype)
     model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
-    # cache sized for the longest prompt bucket (prompts are 6..10 tokens)
-    # plus the requested decode budget
-    kv_len = prompt_bucket(10) + args.max_new_tokens + 1
-    kv_len = max(kv_len, 128)
+    dt = dtype_of(cfg.param_dtype)
+    init = jax.jit(lambda key: jax.tree.map(lambda a: a.astype(dt),
+                                            model.init(key)),
+                   out_shardings=SingleDeviceSharding(device))
+    return cfg, model, init(jax.random.PRNGKey(args.seed))
+
+
+def build_engine(cfg, model, params, args, devices, *, obs=None,
+                 obs_every: int = 1) -> Engine:
+    """An Engine whose dataplane (``args.mode``, emulated OS costs on)
+    spans exactly ``devices``."""
+    dp = Dataplane(DataplaneConfig(mode=args.mode, emulate_costs=True),
+                   mesh=make_local_mesh(devices), tenant=args.tenants[0],
+                   tenants=args.tenants)
+    kv_len = args.kv_len
     if args.block_size > 0:              # keep block_size | kv_cache_len
         kv_len = -(-kv_len // args.block_size) * args.block_size
+    return Engine(model, params, cfg,
+                  ServeConfig(max_batch=args.max_batch,
+                              max_new_tokens=args.max_new_tokens,
+                              kv_cache_len=kv_len,
+                              scheduler=args.scheduler,
+                              block_size=args.block_size,
+                              n_blocks=args.n_blocks,
+                              prefill_chunk=args.prefill_chunk),
+                  dp=dp, eos_id=-1, obs=obs, obs_every=obs_every)
+
+
+def make_requests(cfg, args, *, keep_logits: bool = False) -> list[Request]:
+    """``args.requests`` seeded prompts over the length and tenant lists;
+    ``keep_logits`` records each emitted token's logits row."""
+    rng = np.random.default_rng(args.seed)
+    lens, tenants = args.prompt_lens, args.tenants
+    return [Request(rid=i,
+                    prompt=rng.integers(0, cfg.vocab_size, lens[i % len(lens)],
+                                        dtype=np.int32),
+                    max_new_tokens=args.max_new_tokens,
+                    tenant=tenants[i % len(tenants)],
+                    logits=[] if keep_logits else None)
+            for i in range(args.requests)]
+
+
+def main(argv=None) -> None:
+    args = build_parser().parse_args(argv)
+    enable_compile_cache()
+    devices = jax.devices()[:1]
+    cfg, model, params = load_model(args, devices[0])
     # serve-appropriate elastic defaults: deferral share is the decode
     # pressure signal (denied never moves on the serve counter block)
     elastic = apply_overrides(
@@ -91,23 +187,13 @@ def main() -> None:
     obs = ObsConfig(timeline=args.timeline or elastic.enabled)
     timeline = CounterTimeline(source=f"serve/{args.arch}") \
         if obs.timeline else None
-    eng = Engine(model, params, cfg,
-                 ServeConfig(max_batch=args.max_batch,
-                             max_new_tokens=args.max_new_tokens,
-                             kv_cache_len=kv_len,
-                             scheduler=args.scheduler,
-                             block_size=args.block_size,
-                             n_blocks=args.n_blocks,
-                             prefill_chunk=args.prefill_chunk),
-                 eos_id=-1, obs=timeline, obs_every=obs.every)
+    eng = build_engine(cfg, model, params, args, devices, obs=timeline,
+                       obs_every=obs.every)
     controller = None
     if elastic.enabled:
         controller = ServeElasticController(elastic, timeline, eng)
         eng.on_tick = controller.tick
-    rng = np.random.default_rng(0)
-    reqs = [Request(rid=i, prompt=rng.integers(0, cfg.vocab_size, 6 + i % 5),
-                    max_new_tokens=args.max_new_tokens)
-            for i in range(args.requests)]
+    reqs = make_requests(cfg, args)
     t0 = time.perf_counter()
     done = eng.run(reqs)
     dt = time.perf_counter() - t0
@@ -115,6 +201,7 @@ def main() -> None:
     ttft = [r.t_first - t0 for r in done if r.t_first is not None]
     print(f"served {len(done)} requests, {toks} tokens "
           f"in {dt:.2f}s ({toks/dt:.1f} tok/s, {args.scheduler} scheduler, "
+          f"{args.mode} dataplane on {jax.devices()[0].platform}, "
           f"{eng.decode_compile_count()} decode compiles, "
           f"mean TTFT {1e3*sum(ttft)/max(len(ttft),1):.0f} ms)")
     for r in done[:3]:

@@ -49,6 +49,7 @@ from repro.configs.base import (
 from repro.core import CounterTimeline, Dataplane
 from repro.core.policies import QuotaPolicy, TelemetryPolicy
 from repro.data import DataConfig, ShardedLoader, SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.models import build_model
 from repro.runtime import ElasticController, run_loop
@@ -78,6 +79,7 @@ def main() -> None:
                          "windows (implies --timeline; docs/elasticity.md)")
     ap.add_argument("overrides", nargs="*", default=[])
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_model_config(args.arch, smoke=args.smoke)
     model = build_model(cfg)
@@ -93,7 +95,7 @@ def main() -> None:
                     or bool(args.timeline_sink))
     run = RunConfig(train=train, obs=obs, elastic=elastic)
 
-    mesh = make_local_mesh()
+    mesh = make_local_mesh(jax.devices())
     policies = None
     if elastic.enabled and elastic.meter_quota_bytes:
         # observe-only metering: runtime traffic over the budget marks the

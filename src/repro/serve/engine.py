@@ -126,6 +126,9 @@ class Request:                       # caller-supplied and prompt is an
     out_tokens: list = field(default_factory=list)
     done: bool = False
     t_first: float | None = None     # perf_counter stamp of the first token
+    # a list here makes the engine append, per emitted token, the float32
+    # logits row it was sampled from (the serve-vs-reference check)
+    logits: list | None = None
 
 
 def sample(logits: jax.Array, rng, temperature: float):
@@ -325,7 +328,6 @@ class Engine:
                      "wfq_grants": 0, "occupancy_steps": 0,
                      "preemptions": 0, "restores": 0})
         self._tenant_ids: dict[str, int] = {}
-        self._decode_shapes: set[tuple] = set()
 
     def _tenant_id(self, tenant: str) -> int:
         """Stable small integer per tenant (for the slot tenant vector)."""
@@ -410,10 +412,14 @@ class Engine:
         stats["tokens"] += len(r.out_tokens)
         done.append(r)
 
-    def _emit(self, r: Request, token: int) -> None:
+    def _emit(self, r: Request, token: int, logits, row: int) -> None:
+        """Append ``token`` to ``r``; ``logits[row, -1]`` is the row it
+        was sampled from, copied to the host only when ``r`` asks."""
         if not r.out_tokens:
             r.t_first = time.perf_counter()
         r.out_tokens.append(token)
+        if r.logits is not None:
+            r.logits.append(np.asarray(logits[row, -1], np.float32))
 
     # ------------------------------------------------------------------
     # public entry
@@ -631,7 +637,7 @@ class Engine:
             rng, key = jax.random.split(rng)
             t = int(np.asarray(sample(logits[:, -1, :], key,
                                       self.scfg.temperature))[0])
-            self._emit(r, t)
+            self._emit(r, t, logits, 0)
             if t == self.eos_id or limit <= 1:
                 self._finish(r, done)                # slot stays free
                 slots[slot] = None
@@ -862,14 +868,11 @@ class Engine:
             starved = 0
 
             if self.paged:
-                self._decode_shapes.add(("pool", B,
-                                         self._tables_len * scfg.block_size))
                 logits, cache = self._step_pool(
                     self.params, jnp.asarray(tok), cache,
                     jnp.asarray(self._tables), jnp.asarray(vecs["pos"]),
                     jnp.asarray(vecs["active"]))
             else:
-                self._decode_shapes.add(("slots", B, scfg.kv_cache_len))
                 logits, cache = self._step_slots(self.params,
                                                  jnp.asarray(tok), cache,
                                                  jnp.asarray(vecs["pos"]))
@@ -878,7 +881,7 @@ class Engine:
             for i in active:
                 r = slots[i]
                 t = int(nxt[i])
-                self._emit(r, t)
+                self._emit(r, t, logits, i)
                 self.tenant_stats[r.tenant]["occupancy_steps"] += 1
                 ntok[i] += 1
                 vecs["pos"][i] += 1
@@ -913,14 +916,13 @@ class Engine:
                       for r in batch_reqs]
             active = np.ones(b, bool)
             for j, (r, t) in enumerate(zip(batch_reqs, np.asarray(tok)[:, 0])):
-                self._emit(r, int(t))
+                self._emit(r, int(t), logits, j)
                 if t == self.eos_id or limits[j] <= 1:
                     active[j] = False
 
             for i in range(self.scfg.max_new_tokens - 1):
                 if not active.any():
                     break
-                self._decode_shapes.add(("gang", b, cache_len))
                 pos = jnp.asarray(prompt_len + i, jnp.int32)
                 logits, cache = self._step(self.params, tok, cache, pos)
                 rng, k = jax.random.split(rng)
@@ -928,7 +930,7 @@ class Engine:
                 arr = np.asarray(tok)[:, 0]
                 for j, r in enumerate(batch_reqs):
                     if active[j]:
-                        self._emit(r, int(arr[j]))
+                        self._emit(r, int(arr[j]), logits, j)
                         # a slot whose request hits EOS or its token budget
                         # goes IDLE for the rest of the gang — the convoy
                         # effect continuous slot refill removes
@@ -983,20 +985,11 @@ class Engine:
     def decode_compile_count(self) -> int:
         """Decode-step compilations so far (jit cache entries across the
         gang and slot decode steps) — continuous batching holds this at 1
-        per engine; gang scheduling pays one per distinct batch shape.
-        Falls back to the engine's own distinct-decode-shape count if the
-        jit cache stats API is unavailable (same value: one compile per
-        distinct shape signature)."""
-        n = 0
-        for f in (getattr(self, "_step_slots", None),
-                  getattr(self, "_step_pool", None), self._step):
-            if f is None:
-                continue
-            try:
-                n += f._cache_size()
-            except Exception:           # jit cache introspection moved
-                return len(self._decode_shapes)
-        return n
+        per engine; gang scheduling pays one per distinct batch shape."""
+        return sum(f._cache_size()
+                   for f in (getattr(self, "_step_slots", None),
+                             getattr(self, "_step_pool", None), self._step)
+                   if f is not None)
 
 
 __all__ = ["Engine", "Request", "ServeError", "WFQScheduler", "sample",

@@ -327,3 +327,34 @@ def test_prefill_chunk_logits_and_cache_bitwise(smoke_model):
     for name in ("k", "v"):
         np.testing.assert_array_equal(np.asarray(cache_w[name]),
                                       np.asarray(cache_c[name]))
+
+
+@pytest.mark.parametrize("block_size", [0, 8])
+def test_served_logits_match_cache_free_forward(block_size):
+    """Prefill (whole and chunked) then decode through the cache gives the
+    logits of a cache-free full forward over the same tokens — the
+    serve-side check ``chip_smoke.py`` makes at full width on the chip,
+    here at smoke size in float32 (so only summation order differs)."""
+    from repro.layers.embedding import logits
+
+    cfg = get_model_config("granite-3-2b", smoke=True)
+    model = build_model(cfg)
+    params = model.init(RNG)
+    eng = Engine(model, params, cfg,
+                 ServeConfig(max_batch=2, max_new_tokens=6, kv_cache_len=64,
+                             block_size=block_size, prefill_chunk=16),
+                 eos_id=-1)
+    rng = np.random.default_rng(0)
+    reqs = [Request(rid=i, prompt=rng.integers(0, cfg.vocab_size, n,
+                                               dtype=np.int32),
+                    max_new_tokens=6, logits=[])
+            for i, n in enumerate((5, 23, 40))]        # 40 > chunk: chunked
+    for r in eng.run(reqs):
+        fed = np.concatenate([r.prompt, np.asarray(r.out_tokens[:-1])])
+        x = model.apply(params, {"tokens": jnp.asarray(fed)[None]},
+                        impl="naive")[0]
+        ref = np.asarray(logits(params["embed"], x))[0]
+        want = ref[len(r.prompt) - 1 + np.arange(len(r.out_tokens))]
+        got = np.stack(r.logits)
+        assert got.shape == want.shape == (6, cfg.vocab_size)
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)

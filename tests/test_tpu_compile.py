@@ -1,0 +1,64 @@
+"""Ahead-of-time compiles of the Pallas dataplane kernels for a TPU v5e.
+
+Nothing here runs: each test lowers a kernel against a *described*
+``v5e:2x2`` topology and compiles it with the TPU compiler, which
+refuses what interpret mode accepts (scalar stores to VMEM, slices not
+aligned to the tiling, ragged DMAs).  The payloads are the ones the
+served granite-3-2b path and the calibration probe hand the kernels.
+
+The topology is described only inside the module fixture — never while
+a module is imported — because only one process at a time may load the
+TPU compiler's library; the fixture skips where it cannot be described.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.dataplane import bounce_copy, mediated_cost
+
+PAYLOADS = [
+    pytest.param((4, 1, 2048), jnp.bfloat16, id="decode-activation"),
+    pytest.param((4, 4096, 8, 64), jnp.bfloat16, id="kv-stripe"),
+    pytest.param((8193,), jnp.float32, id="ragged-f32"),
+    pytest.param((256,), jnp.float32, id="calibration-probe"),
+]
+
+KERNELS = [
+    pytest.param(lambda x: bounce_copy(x, copies=2, interpret=False),
+                 id="bounce_copy"),
+    pytest.param(lambda x: mediated_cost(x, 1000, copies=1,
+                                         interpret=False)[0],
+                 id="mediated_cost"),
+]
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                # no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("kernel", KERNELS)
+@pytest.mark.parametrize("shape,dtype", PAYLOADS)
+def test_dataplane_kernel_compiles_for_v5e(one_chip, kernel, shape, dtype):
+    arg = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    compiled = jax.jit(kernel).lower(arg).compile()
+    assert "tpu_custom_call" in compiled.as_text()
