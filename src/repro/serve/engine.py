@@ -65,6 +65,16 @@ decode ticks of co-resident slots — a long prompt no longer monopolizes
 the engine, bounding co-residents' p99 TTFT — and every chunk pays its
 mediation cost through the same fused pipeline as a decode tick.
 
+**Spans** (docs/observability.md): every continuous-path tick is a
+``serve/tick`` profiler span holding its phases — ``serve/schedule``,
+``serve/prefill`` (one whole prefill or one chunk), ``serve/blocks``,
+``serve/decode``, ``serve/sample``, ``serve/emit``, ``serve/observe`` —
+and every program is a named function (``serve_decode``,
+``serve_prefill``, ``serve_prefill_chunk``, ...), so a
+``jax.profiler`` trace of ``Engine.run`` ties each device module to its
+phase on one clock.  Outside a profiler session a span costs about a
+microsecond.
+
 ``scheduler="gang"`` keeps the legacy behaviour — admit up to
 ``max_batch`` requests, batch-prefill them left-padded, decode the gang
 to completion with shape-derived (recompiling) prefill/decode steps —
@@ -88,6 +98,7 @@ from dataclasses import dataclass, field
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig, ServeConfig
 from repro.core import telemetry as tl
@@ -206,19 +217,27 @@ class Engine:
         self.obs = obs
         self.obs_every = max(int(obs_every), 1)
         self._obs_tick_no = 0
-        # control-plane hook: called as ``on_tick(engine)`` right after
-        # each timeline snapshot lands, so a ServeElasticController
+        # control-plane hook: called as ``on_tick(engine)`` once per
+        # decode tick, after the timeline snapshot that is due (if a
+        # timeline is attached), so a ServeElasticController
         # (runtime/elastic.py) can observe the fresh window and move the
         # slot budget while the engine is mid-run
         self.on_tick = None
         # cache sharding edges are issued inside the traced prefill, so
         # policy enforcement/telemetry happen once per compiled shape (like
         # every other dataplane edge), not once per host batching round
-        self._prefill = jax.jit(
-            lambda p, b, c: model.prefill(p, b, kv_cache_constrain(dp, c),
-                                          dp=dp))
-        self._step = jax.jit(
-            lambda p, t, c, pos: model.decode_step(p, t, c, pos, dp=dp))
+        # every program is a named function: XLA names its module
+        # ``jit_<name>`` on the device and ``PjitFunction(<name>)`` on the
+        # host, which is how a profiler trace ties device time to the
+        # engine's phases (docs/observability.md "Spans")
+        def gang_prefill(p, b, c):
+            return model.prefill(p, b, kv_cache_constrain(dp, c), dp=dp)
+
+        def gang_decode(p, t, c, pos):
+            return model.decode_step(p, t, c, pos, dp=dp)
+
+        self._prefill = jax.jit(gang_prefill)
+        self._step = jax.jit(gang_decode)
         step_slots = getattr(model, "decode_step_slots", None)
         self._slot_support = step_slots is not None
         if self._slot_support:
@@ -226,7 +245,7 @@ class Engine:
             # whose cache lands directly in the target slot of the
             # persistent cache (one dispatch per admitted request, one
             # compile per prompt bucket)
-            def _prefill_into_slot(p, t, pc, cache, slot, last):
+            def serve_prefill(p, t, pc, cache, slot, last):
                 logits, pc = model.prefill(p, {"tokens": t},
                                            kv_cache_constrain(dp, pc),
                                            dp=dp, last_pos=last)
@@ -234,14 +253,14 @@ class Engine:
                 # cross-attention state leaves at their batch row
                 return logits, state_slot_insert(cache, pc, slot)
 
+            def serve_decode(p, t, c, pos):
+                return step_slots(p, t, c, pos, dp=dp)
+
             # the persistent cache is donated: XLA updates it in place
             # instead of copying the full buffer per tick / per insert
             # (a no-op with a warning on backends without aliasing)
-            self._prefill_slot = jax.jit(_prefill_into_slot,
-                                         donate_argnums=(3,))
-            self._step_slots = jax.jit(
-                lambda p, t, c, pos: step_slots(p, t, c, pos, dp=dp),
-                donate_argnums=(2,))
+            self._prefill_slot = jax.jit(serve_prefill, donate_argnums=(3,))
+            self._step_slots = jax.jit(serve_decode, donate_argnums=(2,))
 
         # True for the recurrent families (mamba/xLSTM state): the engine
         # prefills them at exact prompt length — right padding advances a
@@ -279,40 +298,48 @@ class Engine:
                 (serve.max_batch * serve.kv_cache_len // bs)
             self._tables_len = self._n_usable
 
-            def _pool_step(p, t, pool, tables, pos, act):
+            def serve_decode(p, t, pool, tables, pos, act):
                 dense = kv_pool_gather(pool, tables, bs)
                 logits, dense = step_slots(p, t, dense, pos, dp=dp)
                 return logits, kv_pool_scatter_token(pool, dense, tables,
                                                      pos, act, bs)
 
-            self._step_pool = jax.jit(_pool_step, donate_argnums=(2,))
-            self._pool_insert = jax.jit(
-                lambda pool, pc, ids: kv_pool_insert(pool, pc, ids, bs),
-                donate_argnums=(0,))
-            self._prefill_last = jax.jit(
-                lambda p, t, c, last: model.prefill(
-                    p, {"tokens": t}, kv_cache_constrain(dp, c), dp=dp,
-                    last_pos=last))
+            def serve_pool_insert(pool, pc, ids):
+                return kv_pool_insert(pool, pc, ids, bs)
+
+            def serve_prefill(p, t, c, last):
+                return model.prefill(p, {"tokens": t},
+                                     kv_cache_constrain(dp, c), dp=dp,
+                                     last_pos=last)
+
+            self._step_pool = jax.jit(serve_decode, donate_argnums=(2,))
+            self._pool_insert = jax.jit(serve_pool_insert,
+                                        donate_argnums=(0,))
+            self._prefill_last = jax.jit(serve_prefill)
 
         # ---- chunked prefill (prefill_chunk > 0) ----------------------
         chunk_fn = getattr(model, "prefill_chunk", None)
         self.chunked = (serve.prefill_chunk > 0 and chunk_fn is not None
                         and self._slot_support)
         if self.chunked:
-            self._chunk = jax.jit(
-                lambda p, t, c, off, last: chunk_fn(
-                    p, {"tokens": t}, kv_cache_constrain(dp, c), off, dp=dp,
-                    last_pos=last),
-                donate_argnums=(2,))
+            def serve_prefill_chunk(p, t, c, off, last):
+                return chunk_fn(p, {"tokens": t}, kv_cache_constrain(dp, c),
+                                off, dp=dp, last_pos=last)
+
+            def serve_chunk_scatter(pool, pc, trow, off):
+                return kv_pool_scatter_chunk(pool, pc, trow, off,
+                                             serve.prefill_chunk, bs)
+
+            def serve_slot_insert(c, pc, s):
+                return state_slot_insert(c, pc, s)
+
+            self._chunk = jax.jit(serve_prefill_chunk, donate_argnums=(2,))
             if self.paged:
-                self._chunk_scatter = jax.jit(
-                    lambda pool, pc, trow, off: kv_pool_scatter_chunk(
-                        pool, pc, trow, off, serve.prefill_chunk, bs),
-                    donate_argnums=(0,))
+                self._chunk_scatter = jax.jit(serve_chunk_scatter,
+                                              donate_argnums=(0,))
             else:
-                self._slot_ins = jax.jit(
-                    lambda c, pc, s: state_slot_insert(c, pc, s),
-                    donate_argnums=(0,))
+                self._slot_ins = jax.jit(serve_slot_insert,
+                                         donate_argnums=(0,))
 
         # per-run slot bookkeeping (reset by _run_continuous)
         self._prefills: dict[int, dict] = {}
@@ -379,20 +406,20 @@ class Engine:
         return queue[:1], queue[1:]
 
     def _obs_snapshot(self, *, active: int, queued: int) -> None:
-        """Feed the attached timeline one engine tick: the serve counter
-        block (WFQ grants / tokens / occupancy / deferrals in telemetry
-        column layout) plus slot-level run gauges."""
-        if self.obs is None:
-            return
-        self._obs_tick_no += 1
-        if self._obs_tick_no % self.obs_every:
-            return
-        ctrs, tenants = self.runtime_counters()
-        gauges = {"active_slots": active, "queued": queued}
-        if self.paged and getattr(self, "_alloc", None) is not None:
-            gauges["free_blocks"] = self._alloc.free_blocks
-        self.obs.snapshot_block(self._obs_tick_no, ctrs, tenants,
-                                gauges=gauges)
+        """The end of one engine tick, seen from outside: the attached
+        timeline gets the snapshot that is due (the serve counter block —
+        WFQ grants / tokens / occupancy / deferrals in telemetry column
+        layout — plus slot-level run gauges), then ``on_tick`` fires,
+        every tick, with or without a timeline."""
+        if self.obs is not None:
+            self._obs_tick_no += 1
+            if self._obs_tick_no % self.obs_every == 0:
+                ctrs, tenants = self.runtime_counters()
+                gauges = {"active_slots": active, "queued": queued}
+                if self.paged and getattr(self, "_alloc", None) is not None:
+                    gauges["free_blocks"] = self._alloc.free_blocks
+                self.obs.snapshot_block(self._obs_tick_no, ctrs, tenants,
+                                        gauges=gauges)
         if self.on_tick is not None:
             self.on_tick(self)
 
@@ -693,21 +720,23 @@ class Engine:
             self._slot_seq += 1
             self._slot_started[slot] = self._slot_seq
             return cache, rng
-        pcache = self.model.init_cache(1, cover)
         last = np.asarray([eff - 1], np.int32)
-        if self.paged:
-            logits, pcache = self._prefill_last(self.params,
-                                                jnp.asarray(toks), pcache,
-                                                jnp.asarray(last))
-            cache = self._pool_insert(cache, pcache,
-                                      jnp.asarray(ids, jnp.int32))
-        else:
-            logits, cache = self._prefill_slot(self.params,
-                                               jnp.asarray(toks), pcache,
-                                               cache, jnp.int32(slot),
-                                               jnp.asarray(last))
-        return self._activate(r, slot, logits, cache, slots, vecs, tok,
-                              ntok, done, rng, eff=eff, k=k)
+        with TraceAnnotation("serve/prefill", rid=r.rid, slot=slot, offset=0,
+                             tokens=eff):
+            pcache = self.model.init_cache(1, cover)
+            if self.paged:
+                logits, pcache = self._prefill_last(self.params,
+                                                    jnp.asarray(toks), pcache,
+                                                    jnp.asarray(last))
+                cache = self._pool_insert(cache, pcache,
+                                          jnp.asarray(ids, jnp.int32))
+            else:
+                logits, cache = self._prefill_slot(self.params,
+                                                   jnp.asarray(toks), pcache,
+                                                   cache, jnp.int32(slot),
+                                                   jnp.asarray(last))
+            return self._activate(r, slot, logits, cache, slots, vecs, tok,
+                                  ntok, done, rng, eff=eff, k=k)
 
     def _advance_chunk(self, cache, slots, vecs, tok, ntok, done, rng):
         """Advance the oldest chunk-prefilling slot by ONE chunk (paying
@@ -717,25 +746,27 @@ class Engine:
         st = self._prefills[slot]
         C = self.scfg.prefill_chunk
         off = st["off"]
-        chunk = st["toks"][:, off:off + C]
-        last = np.asarray([st["eff"] - 1], np.int32)
-        logits, st["pcache"] = self._chunk(self.params, jnp.asarray(chunk),
-                                           st["pcache"], jnp.int32(off),
-                                           jnp.asarray(last))
-        if self.paged:                   # scatter the chunk's blocks now
-            cache = self._chunk_scatter(cache, st["pcache"],
-                                        jnp.asarray(self._tables[slot]),
-                                        jnp.int32(off))
-        st["off"] = off + C
-        if st["off"] < st["cover"]:
-            self._prefill_q.append(slot)
-            return cache, rng
-        self._prefills.pop(slot)         # last chunk: logits are at eff-1
-        if not self.paged:
-            cache = self._slot_ins(cache, st["pcache"], jnp.int32(slot))
-        return self._activate(st["r"], slot, logits, cache, slots, vecs,
-                              tok, ntok, done, rng, eff=st["eff"],
-                              k=st["k"])
+        with TraceAnnotation("serve/prefill", rid=st["r"].rid, slot=slot,
+                             offset=off, tokens=min(C, st["eff"] - off)):
+            chunk = st["toks"][:, off:off + C]
+            last = np.asarray([st["eff"] - 1], np.int32)
+            logits, st["pcache"] = self._chunk(
+                self.params, jnp.asarray(chunk), st["pcache"],
+                jnp.int32(off), jnp.asarray(last))
+            if self.paged:               # scatter the chunk's blocks now
+                cache = self._chunk_scatter(cache, st["pcache"],
+                                            jnp.asarray(self._tables[slot]),
+                                            jnp.int32(off))
+            st["off"] = off + C
+            if st["off"] < st["cover"]:
+                self._prefill_q.append(slot)
+                return cache, rng
+            self._prefills.pop(slot)     # last chunk: logits are at eff-1
+            if not self.paged:
+                cache = self._slot_ins(cache, st["pcache"], jnp.int32(slot))
+            return self._activate(st["r"], slot, logits, cache, slots, vecs,
+                                  tok, ntok, done, rng, eff=st["eff"],
+                                  k=st["k"])
 
     def _fill_slots(self, slots, queue, cache, vecs, tok, ntok, done, rng):
         """WFQ slot packing: hand each free slot to the backlogged tenant
@@ -838,35 +869,50 @@ class Engine:
         queue = deque(requests)
         done: list[Request] = []
         starved = 0
-
+        tick = 0
         while queue or vecs["active"].any() or self._prefills:
-            self._enforce_budget(slots, vecs, tok, ntok, queue)
-            cache, rng, granted = self._fill_slots(slots, queue, cache, vecs,
-                                                   tok, ntok, done, rng)
-            if self._prefill_q:          # one chunk per tick, interleaved
-                cache, rng = self._advance_chunk(cache, slots, vecs, tok,
-                                                 ntok, done, rng)
-            if self.paged:               # claim this tick's write blocks
-                for i in np.nonzero(vecs["active"])[0]:
-                    if vecs["active"][i]:
-                        self._ensure_blocks(int(i), slots, vecs, tok, ntok,
-                                            queue)
-            active = np.nonzero(vecs["active"])[0]
-            if not len(active):
-                if not queue and not self._prefills:
-                    break
-                starved = 0 if granted or self._prefills else starved + 1
-                if starved > _MAX_STARVED_ROUNDS:
-                    # pathological rates (≈0): force progress, bypassing
-                    # the bucket, with the queue head
-                    r = queue.popleft()
-                    cache, rng = self._start_request(r, 0, cache, slots,
-                                                     vecs, tok, ntok, done,
-                                                     rng)
-                    starved = 0
-                continue
-            starved = 0
+            tick += 1
+            with TraceAnnotation("serve/tick", tick=tick):
+                with TraceAnnotation("serve/schedule"):
+                    self._enforce_budget(slots, vecs, tok, ntok, queue)
+                    cache, rng, granted = self._fill_slots(
+                        slots, queue, cache, vecs, tok, ntok, done, rng)
+                if self._prefill_q:      # one chunk per tick, interleaved
+                    cache, rng = self._advance_chunk(cache, slots, vecs, tok,
+                                                     ntok, done, rng)
+                if self.paged:           # claim this tick's write blocks
+                    with TraceAnnotation("serve/blocks"):
+                        for i in np.nonzero(vecs["active"])[0]:
+                            if vecs["active"][i]:
+                                self._ensure_blocks(int(i), slots, vecs, tok,
+                                                    ntok, queue)
+                active = np.nonzero(vecs["active"])[0]
+                if not len(active):
+                    if not queue and not self._prefills:
+                        break
+                    starved = 0 if granted or self._prefills else \
+                        starved + 1
+                    if starved > _MAX_STARVED_ROUNDS:
+                        # pathological rates (≈0): force progress,
+                        # bypassing the bucket, with the queue head
+                        r = queue.popleft()
+                        cache, rng = self._start_request(
+                            r, 0, cache, slots, vecs, tok, ntok, done, rng)
+                        starved = 0
+                    continue
+                starved = 0
+                cache, rng = self._decode_tick(tick, active, cache, rng,
+                                               slots, vecs, tok, ntok, done,
+                                               queue)
+        return done
 
+    def _decode_tick(self, tick: int, active, cache, rng, slots, vecs, tok,
+                     ntok, done, queue):
+        """One decode step over every slot, then its sampled tokens emitted
+        (a slot that finishes is freed mid-decode) and the tick observed.
+        Returns (cache, rng)."""
+        scfg = self.scfg
+        with TraceAnnotation("serve/decode", tick=tick, active=len(active)):
             if self.paged:
                 logits, cache = self._step_pool(
                     self.params, jnp.asarray(tok), cache,
@@ -876,8 +922,10 @@ class Engine:
                 logits, cache = self._step_slots(self.params,
                                                  jnp.asarray(tok), cache,
                                                  jnp.asarray(vecs["pos"]))
+        with TraceAnnotation("serve/sample"):
             rng, k = jax.random.split(rng)
             nxt = np.asarray(sample(logits[:, -1, :], k, scfg.temperature))
+        with TraceAnnotation("serve/emit", tokens=len(active)):
             for i in active:
                 r = slots[i]
                 t = int(nxt[i])
@@ -891,9 +939,10 @@ class Engine:
                     self._finish(r, done)
                     slots[i] = None                  # freed mid-decode
                     self._release_slot(i, vecs)      # blocks back to pool
+        with TraceAnnotation("serve/observe"):
             self._obs_snapshot(active=int(vecs["active"].sum()),
                                queued=len(queue))
-        return done
+        return cache, rng
 
     # ------------------------------------------------------------------
     # gang (legacy baseline): batch to completion, shape-derived compiles
