@@ -153,64 +153,83 @@ def cache_validity(max_len: int, filled_len) -> jax.Array:
 # ---------------------------------------------------------------------------
 #
 # The paged layout replaces the per-slot stripe with ONE shared pool of
-# fixed-size blocks: ``(layers, n_blocks + 1, block_size, kv_heads,
+# fixed-size blocks: ``(layers, n_blocks + 1, block_size, kv_heads *
 # head_dim)`` per k/v leaf, plus a host-side ``(max_batch, tables_len)``
 # int32 block table mapping each slot's logical block index to a physical
-# pool block.  Physical block 0 is reserved as a shared *null* block:
-# free slots and unallocated table tail entries point at it, so gathers
-# stay total functions of the table (garbage rows are masked by the same
-# per-slot validity that guards stripe decode).  Scatters for inactive
-# slots are routed to the out-of-bounds index ``n_blocks + 1`` and
-# dropped (`mode="drop"`), never corrupting block 0.
+# pool block.  The heads are folded into one lane-dense minor dimension so
+# that a block is contiguous on the device: a (…, kv_heads, head_dim)
+# minor pair narrower than a lane row makes TPU layouts put the block
+# index minor-most instead, and every block read or token write then
+# relayouts the whole pool.  Physical block 0 is reserved as a shared
+# *null* block: free slots and unallocated table tail entries point at
+# it, so reads stay total functions of the table.  Decode reads each
+# slot's live blocks in place (kernels/paged_attention) and writes its
+# one new token with :func:`kv_pool_scatter_token`; :func:`kv_pool_gather`
+# builds the dense view of every table only as the reference.  Prefill
+# writes to padded block ids are routed to the out-of-bounds index
+# ``n_blocks + 1`` and dropped (`mode="drop"`), and an inactive slot's
+# decode write puts back what is there, so block 0 is never corrupted.
 
 def kv_pool_init(layers: int, n_blocks: int, block_size: int, kv_heads: int,
                  head_dim: int, dtype=jnp.bfloat16) -> dict:
     """Block pool with ``n_blocks`` usable blocks (physical ids 1..n_blocks;
     id 0 is the shared null block)."""
-    shape = (layers, n_blocks + 1, block_size, kv_heads, head_dim)
+    shape = (layers, n_blocks + 1, block_size, kv_heads * head_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
 def kv_pool_gather(pool: dict, tables, block_size: int) -> dict:
-    """Materialise a dense (layers, B, T*block_size, KVH, hd) decode cache
-    from the pool by per-slot block table (B, T) — the paged engine's view
-    for the UNCHANGED fixed-shape decode step.  Rows mapped to the null
-    block read zeros; validity masking keeps them unattended."""
+    """Reference: materialise a dense (layers, B, T*block_size, KVH*hd)
+    cache from the pool by per-slot block table (B, T) — reshaped to
+    ``(…, KVH, hd)`` it is the view the stripe layout's fixed-shape decode
+    step reads.  Rows mapped to the null block read zeros; validity
+    masking keeps them unattended."""
     tables = jnp.asarray(tables, jnp.int32)
 
     def one(buf):
-        ll, _, bs, kvh, hd = buf.shape
+        ll, _, bs, d = buf.shape
         b, t = tables.shape
-        g = buf[:, tables]                     # (L, B, T, bs, KVH, hd)
-        return g.reshape(ll, b, t * bs, kvh, hd)
+        g = buf[:, tables]                     # (L, B, T, bs, KVH*hd)
+        return g.reshape(ll, b, t * bs, d)
 
     return {name: one(buf) for name, buf in pool.items()}
 
 
-def kv_pool_scatter_token(pool: dict, cache: dict, tables, pos, active,
+def kv_pool_scatter_token(pool: dict, tokens: dict, tables, pos, active,
                           block_size: int) -> dict:
-    """Write back the ONE token each active slot appended this decode tick.
+    """Write the ONE token each active slot appended this decode tick.
 
-    ``cache`` is the gathered dense cache AFTER the decode step (the new
-    token sits at per-slot ``pos``); the token is extracted per slot and
-    scattered to pool block ``tables[slot, pos // block_size]`` at offset
-    ``pos % block_size``.  Inactive slots scatter to the out-of-bounds
-    physical index and are dropped."""
+    ``tokens`` holds each leaf's (L, B, KVH, hd) or (L, B, KVH*hd) new
+    keys / values; slot
+    b's lands in pool block ``tables[b, pos[b] // block_size]`` at offset
+    ``pos[b] % block_size``; an inactive slot writes back what is there.
+    One in-place update per layer and slot: a scatter over the (block,
+    offset) dims would have TPU layouts move them major and relayout the
+    whole pool."""
     tables = jnp.asarray(tables, jnp.int32)
     pos = jnp.asarray(pos, jnp.int32)
     active = jnp.asarray(active, bool)
-    b = tables.shape[0]
-    rows = jnp.arange(b, dtype=jnp.int32)
+    rows = jnp.arange(tables.shape[0], dtype=jnp.int32)
+    blk = tables[rows, pos // block_size]      # (B,) physical ids
+    off = pos % block_size
+    zero = jnp.zeros((), jnp.int32)
 
-    def one(buf, dense):
-        n_total = buf.shape[1]                 # n_blocks + 1
-        tok = dense[:, rows, pos]              # (L, B, KVH, hd)
-        blk = tables[rows, pos // block_size]  # (B,) physical ids
-        blk = jnp.where(active, blk, jnp.int32(n_total))  # OOB → dropped
-        return buf.at[:, blk, pos % block_size].set(
-            tok.astype(buf.dtype), mode="drop")
+    def one(buf, tok):
+        ll, _, _, d = buf.shape
+        tok = tok.reshape(ll, rows.shape[0], 1, 1, 1, d).astype(buf.dtype)
 
-    return {name: one(buf, cache[name]) for name, buf in pool.items()}
+        def layer(l, buf):
+            for i in range(rows.shape[0]):
+                at = (l, blk[i], off[i], zero)
+                old = jax.lax.dynamic_slice(buf, at, (1, 1, 1, d))
+                new = jax.lax.dynamic_index_in_dim(tok[:, i], l, 0, False)
+                buf = jax.lax.dynamic_update_slice(
+                    buf, jnp.where(active[i], new, old), at)
+            return buf
+
+        return jax.lax.fori_loop(0, ll, layer, buf)
+
+    return {name: one(buf, tokens[name]) for name, buf in pool.items()}
 
 
 def kv_pool_insert(pool: dict, prefilled: dict, block_ids,
@@ -222,13 +241,13 @@ def kv_pool_insert(pool: dict, prefilled: dict, block_ids,
     block_ids = jnp.asarray(block_ids, jnp.int32)
 
     def one(buf, src):
-        ll, _, bs, kvh, hd = buf.shape
-        src = src[:, 0]                        # (L, cap, KVH, hd)
+        ll, _, bs, d = buf.shape
+        src = src[:, 0].reshape(ll, -1, d)     # (L, cap, KVH*hd)
         cap = src.shape[1]
         pad = (-cap) % bs
         if pad:
-            src = jnp.pad(src, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        chunks = src.reshape(ll, -1, bs, kvh, hd)   # (L, nblk, bs, KVH, hd)
+            src = jnp.pad(src, ((0, 0), (0, pad), (0, 0)))
+        chunks = src.reshape(ll, -1, bs, d)    # (L, nblk, bs, KVH*hd)
         return buf.at[:, block_ids].set(chunks.astype(buf.dtype),
                                         mode="drop")
 
@@ -247,11 +266,12 @@ def kv_pool_scatter_chunk(pool: dict, cache: dict, table_row, offset,
     nblk = chunk // block_size
 
     def one(buf, dense):
-        ll, _, bs, kvh, hd = buf.shape
+        ll, _, bs, d = buf.shape
+        _, _, _, kvh, hd = dense.shape
         piece = jax.lax.dynamic_slice(
             dense, (0, 0, offset, 0, 0),
             (ll, 1, chunk, kvh, hd))[:, 0]          # (L, chunk, KVH, hd)
-        chunks = piece.reshape(ll, nblk, bs, kvh, hd)
+        chunks = piece.reshape(ll, nblk, bs, d)
         ids = jax.lax.dynamic_slice(table_row, (offset // bs,), (nblk,))
         return buf.at[:, ids].set(chunks.astype(buf.dtype), mode="drop")
 

@@ -36,6 +36,11 @@ class Model:
     # for families without a slot-aware decode path; the serving engine
     # falls back to gang scheduling when absent.
     decode_step_slots: Callable | None = None
+    # Fixed-shape decode over slots whose caches live in a shared block
+    # pool, read in place through per-slot block tables.  None for
+    # families whose cache cannot be paged; the engine then refuses
+    # block_size > 0.
+    decode_step_paged: Callable | None = None
     # Chunked prefill: write one (B, C) chunk at a traced offset.  None for
     # families without it; the engine prefills whole prompts when absent.
     prefill_chunk: Callable | None = None
@@ -66,6 +71,9 @@ def build_model(cfg: ModelConfig) -> Model:
             decode_step_slots=lambda params, token, cache, pos, **kw:
                 m.transformer_decode_step_slots(params, cfg, token, cache,
                                                 pos, **kw),
+            decode_step_paged=lambda params, token, pool, tables, pos, **kw:
+                m.transformer_decode_step_paged(params, cfg, token, pool,
+                                                tables, pos, **kw),
             prefill_chunk=lambda params, batch, cache, offset, **kw:
                 m.transformer_prefill_chunk(params, cfg, batch, cache,
                                             offset, **kw),

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.kernels.paged_attention import paged_decode_attention
 from repro.layers.attention import (
     attend,
     attend_naive,
@@ -107,8 +108,9 @@ def transformer_init(rng, cfg: ModelConfig) -> dict:
 # ---------------------------------------------------------------------------
 
 def _layer(lp, x, *, cfg, dp, positions, window, theta, mode,
-           cache_k=None, cache_v=None, cache_pos=None, kv_len=None,
-           train=False, impl="flash", q_block=512, kv_block=1024):
+           cache_k=None, cache_v=None, cache_pos=None, tables=None,
+           layer=None, kv_len=None, train=False, impl="flash", q_block=512,
+           kv_block=1024):
     a = cfg.attention
     h = rmsnorm(lp["norm1"], x, cfg.norm_eps)
     q, k, v = qkv_project(lp["attn"], h, num_kv_heads=a.num_kv_heads,
@@ -147,6 +149,24 @@ def _layer(lp, x, *, cfg, dp, positions, window, theta, mode,
         o = attend_naive(q, ck, cv, valid[:, None, :],
                          logit_cap=a.logit_softcap)
         new_ck, new_cv = cache_k, cache_v
+    elif mode == "decode_paged":
+        # paged slot decode: cache_k / cache_v are the whole block pool
+        # (layers, n_blocks + 1, bs, KVH·hd).  This layer's slice crosses
+        # the dataplane as its cache edge, and the kernel reads each
+        # slot's blocks through its table from the edge's output — or,
+        # where the pipeline has no stages (bypass: the edge moves
+        # nothing), from the pool in place.  The new token is attended
+        # from k / v and returned for the caller to write into the pool.
+        pool_axes = (None, None, ("kv_heads", "cache_head_dim"))
+        ck = constrain(dp, cache_k[layer], pool_axes, tag="attn/cache_k")
+        cv = constrain(dp, cache_v[layer], pool_axes, tag="attn/cache_v")
+        if dp is not None and dp.pipeline.stages:
+            cache_k, cache_v, layer = ck[None], cv[None], 0
+        o = paged_decode_attention(q[:, 0], cache_k, cache_v, tables,
+                                   cache_pos, k[:, 0], v[:, 0], layer=layer,
+                                   window=window,
+                                   logit_cap=a.logit_softcap)[:, None]
+        new_ck, new_cv = k[:, 0], v[:, 0]
     elif mode == "chunk":
         # chunked prefill: q len C written into the cache at a *traced*
         # offset (cache_pos), attending to everything filled so far.  The
@@ -397,9 +417,47 @@ def transformer_decode_step_slots(params, cfg: ModelConfig, token, cache,
     return logits_fn(params["embed"], x, dp=dp), {"k": caches[0], "v": caches[1]}
 
 
+def transformer_decode_step_paged(params, cfg: ModelConfig, token, pool,
+                                  tables, pos, *, dp=None):
+    """One fixed-shape decode step over persistent slots whose caches live
+    in a shared block pool.
+
+    token: (B, 1) int32; pool: {"k", "v"} of (layers, n_blocks + 1, bs,
+    KVH·hd) (``kv_pool_init``); tables: (B, T) int32 block ids; pos: (B,)
+    int32, each slot's write position (the pool holds ``[0, pos)``).  Each
+    layer attends each slot's live blocks straight from the pool
+    (kernels/paged_attention) plus its new token, so nothing of the size
+    of the whole table is built.  Returns ``(logits, new)`` with ``new``
+    the appended token's {"k", "v"} of (layers, B, KVH, hd), which the
+    caller writes into the pool (``kv_pool_scatter_token``).  Shapes depend
+    only on the slot and pool geometry: one compile per engine."""
+    dtype = dtype_of(cfg.dtype)
+    pos = jnp.asarray(pos, jnp.int32)
+    tables = jnp.asarray(tables, jnp.int32)
+    x = embed(params["embed"], token, dtype, dp=dp)
+    positions = pos[:, None]                       # (B, 1) per-slot RoPE
+    window_arr, theta_arr = layer_flags(cfg)
+
+    def body(x, xs):
+        lp, w, th, layer = xs
+        x, _aux, nk, nv = _layer(lp, x, cfg=cfg, dp=dp, positions=positions,
+                                 window=w, theta=th, mode="decode_paged",
+                                 cache_k=pool["k"], cache_v=pool["v"],
+                                 cache_pos=pos, tables=tables, layer=layer)
+        return x, (nk, nv)
+
+    xs = (params["layers"], jnp.asarray(window_arr), jnp.asarray(theta_arr),
+          jnp.arange(cfg.num_layers, dtype=jnp.int32))
+    x, new = jax.lax.scan(body, x, xs)
+    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
+    from repro.layers.embedding import logits as logits_fn
+    return logits_fn(params["embed"], x, dp=dp), {"k": new[0], "v": new[1]}
+
+
 __all__ = [
     "transformer_init", "transformer_apply", "transformer_loss",
     "transformer_init_cache", "transformer_prefill",
     "transformer_prefill_chunk", "transformer_decode_step",
-    "transformer_decode_step_slots", "layer_flags",
+    "transformer_decode_step_slots", "transformer_decode_step_paged",
+    "layer_flags",
 ]

@@ -43,11 +43,14 @@ per-tick timeline artifact and sparkline panels (docs/observability.md).
 **Paged KV cache** (``ServeConfig.block_size > 0``, docs/serving.md):
 instead of one ``kv_cache_len`` stripe per slot, the engine owns ONE
 shared pool of fixed-size blocks plus a per-slot host block table
-(layers/kvcache.py ``kv_pool_*`` helpers).  Each decode tick gathers
-every slot's blocks into a dense cache, runs the UNCHANGED fixed-shape
-slot decode, and scatters the one written token back — gather is a total
-function of the table and garbage rows are validity-masked, so paged
-decode is bit-identical to stripe decode at temperature 0.  Prefill
+(layers/kvcache.py ``kv_pool_*`` helpers).  Each decode tick runs the
+model's paged decode step (``Model.decode_step_paged``): every layer
+attends each slot's live blocks in place through its table
+(kernels/paged_attention) plus the slot's new token, and the new tokens
+are written into the donated pool after the layer scan — no op holds the
+``max_batch × tables_len × block_size`` positions of the whole tables.
+At temperature 0 it emits the stripe layout's tokens, with logits equal
+within float tolerance (tests/test_serve_paged.py).  Prefill
 allocates a request's cover blocks at grant; decode growth claims one
 block at a time, and pool pressure (or a lowered slot budget,
 :meth:`Engine.set_slot_budget`) *preempts* a running slot: its emitted
@@ -107,7 +110,6 @@ from repro.core.policies import QoSPolicy
 from repro.layers.kvcache import (
     BlockAllocator,
     kv_cache_constrain,
-    kv_pool_gather,
     kv_pool_init,
     kv_pool_insert,
     kv_pool_scatter_chunk,
@@ -272,10 +274,12 @@ class Engine:
         # ---- paged KV block pool (block_size > 0) ---------------------
         bs = serve.block_size
         self.paged = bs > 0
+        step_paged = getattr(model, "decode_step_paged", None)
         if self.paged:
             spec = (jax.eval_shape(lambda: model.init_cache(1, bs))
                     if self._slot_support else None)
-            pageable = (isinstance(spec, dict) and set(spec) == {"k", "v"}
+            pageable = (step_paged is not None and isinstance(spec, dict)
+                        and set(spec) == {"k", "v"}
                         and all(len(v.shape) == 5 for v in spec.values()))
             if not pageable:
                 # name the family and the flag — never a capacity message:
@@ -299,9 +303,12 @@ class Engine:
             self._tables_len = self._n_usable
 
             def serve_decode(p, t, pool, tables, pos, act):
-                dense = kv_pool_gather(pool, tables, bs)
-                logits, dense = step_slots(p, t, dense, pos, dp=dp)
-                return logits, kv_pool_scatter_token(pool, dense, tables,
+                # each slot's blocks are read in place; an inactive slot
+                # reads none (position 0), its logits row is never read
+                # and its token write puts back what is there
+                logits, new = step_paged(p, t, pool, tables,
+                                         jnp.where(act, pos, 0), dp=dp)
+                return logits, kv_pool_scatter_token(pool, new, tables,
                                                      pos, act, bs)
 
             def serve_pool_insert(pool, pc, ids):
@@ -912,7 +919,11 @@ class Engine:
         (a slot that finishes is freed mid-decode) and the tick observed.
         Returns (cache, rng)."""
         scfg = self.scfg
-        with TraceAnnotation("serve/decode", tick=tick, active=len(active)):
+        span = {"tick": tick, "active": len(active)}
+        if self.paged:                   # the share of the tables readable
+            span["blocks"] = sum(len(self._slot_blocks[i]) for i in active)
+            span["table_blocks"] = self._tables.size
+        with TraceAnnotation("serve/decode", **span):
             if self.paged:
                 logits, cache = self._step_pool(
                     self.params, jnp.asarray(tok), cache,

@@ -77,7 +77,7 @@ def test_kv_pool_insert_then_gather_bitwise():
            kv_cache_init(L, 1, 8, KVH, hd, dtype=jnp.float32).items()}
     pool = kv_pool_insert(pool, pre, jnp.asarray([2, 5], jnp.int32), bs)
     dense = kv_pool_gather(pool, jnp.asarray([[2, 5, 0]], jnp.int32), bs)
-    assert dense["k"].shape == (L, 1, 12, KVH, hd)
+    assert dense["k"].shape == (L, 1, 12, KVH * hd)
     np.testing.assert_array_equal(np.asarray(dense["k"][:, 0, :8]), 7.0)
     # the unallocated table tail reads the null block: zeros
     assert float(jnp.abs(dense["k"][:, 0, 8:]).max()) == 0.0
@@ -92,7 +92,9 @@ def test_kv_pool_scatter_token_targets_and_drops():
     dense = kv_pool_gather(pool, tables, bs)
     dense = {k: v.at[:, 0, 5].set(9.0).at[:, 1, 1].set(4.0)
              for k, v in dense.items()}
-    pool = kv_pool_scatter_token(pool, dense, tables, pos, active, bs)
+    rows = jnp.arange(2)
+    tok = {k: v[:, rows, pos] for k, v in dense.items()}   # (L, B, KVH·hd)
+    pool = kv_pool_scatter_token(pool, tok, tables, pos, active, bs)
     # slot 0, pos 5 → physical block tables[0, 1] = 2 at offset 1
     assert float(pool["k"][0, 2, 1].max()) == 9.0
     assert float(jnp.abs(pool["k"][0, 3]).max()) == 0.0   # inactive dropped

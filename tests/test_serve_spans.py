@@ -127,6 +127,20 @@ def test_one_decode_span_per_decode_tick(traced):
         sum(len(t) for t in traced["under"].values())
 
 
+def test_decode_span_counts_blocks(traced):
+    """Paged decode spans carry the blocks the active slots hold and the
+    blocks of the whole tables, ``max_batch × tables_len``: their ratio is
+    the share of the tables the decode step can read."""
+    b, bs = PAGED_CHUNKED["max_batch"], PAGED_CHUNKED["block_size"]
+    tables_len = b * PAGED_CHUNKED["kv_cache_len"] // bs
+    decode = [ev[3] for ev in _spans(traced["lines"])
+              if ev[2] == "serve/decode"]
+    assert decode
+    for st in decode:
+        assert st["table_blocks"] == b * tables_len
+        assert st["active"] <= st["blocks"] < st["table_blocks"]
+
+
 def test_prefill_spans_carry_rid_one_per_chunk(traced):
     prefill = [ev[3] for ev in _spans(traced["lines"])
                if ev[2] == "serve/prefill"]

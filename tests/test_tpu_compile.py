@@ -1,10 +1,11 @@
-"""Ahead-of-time compiles of the Pallas dataplane kernels for a TPU v5e.
+"""Ahead-of-time compiles of the Pallas kernels for a TPU v5e.
 
 Nothing here runs: each test lowers a kernel against a *described*
 ``v5e:2x2`` topology and compiles it with the TPU compiler, which
 refuses what interpret mode accepts (scalar stores to VMEM, slices not
 aligned to the tiling, ragged DMAs).  The payloads are the ones the
-served granite-3-2b path and the calibration probe hand the kernels.
+served granite-3-2b path and the calibration probe hand the dataplane
+kernels, and the paged decode attention at granite-3-2b's widths.
 
 The topology is described only inside the module fixture — never while
 a module is imported — because only one process at a time may load the
@@ -20,6 +21,7 @@ from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.dataplane import bounce_copy, mediated_cost
+from repro.kernels.paged_attention import paged_decode_attention
 
 PAYLOADS = [
     pytest.param((4, 1, 2048), jnp.bfloat16, id="decode-activation"),
@@ -61,4 +63,28 @@ def one_chip():
 def test_dataplane_kernel_compiles_for_v5e(one_chip, kernel, shape, dtype):
     arg = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     compiled = jax.jit(kernel).lower(arg).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_paged_attention_compiles_for_v5e(one_chip):
+    """granite-3-2b's decode attention over its served pool: batch 3, 32
+    query heads on 8 KV heads of 64, 40 layers of 484 blocks of 16,
+    tables of 483, at a traced layer and window."""
+    b, h, kvh, hd, bs, n_blocks, t_len = 3, 32, 8, 64, 16, 483, 483
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = arg((40, n_blocks + 1, bs, kvh * hd), jnp.bfloat16)
+    new = arg((b, kvh, hd), jnp.bfloat16)
+    i32 = jnp.int32
+
+    def step(q, k_pool, v_pool, tables, pos, k_new, v_new, layer, window):
+        return paged_decode_attention(q, k_pool, v_pool, tables, pos, k_new,
+                                      v_new, layer=layer, window=window,
+                                      interpret=False)
+
+    compiled = jax.jit(step).lower(
+        arg((b, h, hd), jnp.bfloat16), pool, pool, arg((b, t_len), i32),
+        arg((b,), i32), new, new, arg((), i32), arg((), i32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
