@@ -36,13 +36,15 @@ _ARCH_MODULES = {
     "arctic-480b": "repro.configs.arctic_480b",
     "grok-1-314b": "repro.configs.grok_1_314b",
     "xlstm-350m": "repro.configs.xlstm_350m",
+    "trinity-mini": "repro.configs.trinity_mini",
 }
 
 ARCHS = tuple(_ARCH_MODULES)
 
 # long_500k applicability: run for sub-quadratic archs only
 # (ModelConfig.is_subquadratic).
-LONG_CONTEXT_ARCHS = ("gemma3-4b", "gemma3-1b", "hymba-1.5b", "xlstm-350m")
+LONG_CONTEXT_ARCHS = ("gemma3-4b", "gemma3-1b", "hymba-1.5b", "xlstm-350m",
+                      "trinity-mini")
 
 
 def get_model_config(name: str, *, smoke: bool = False) -> ModelConfig:

@@ -33,6 +33,34 @@ class MoEConfig:
     router_jitter: float = 0.0
     router_z_loss: float = 1e-3
     load_balance_loss: float = 1e-2
+    # Router scores: "softmax" over the experts (arctic, grok), or
+    # "sigmoid" per expert (afmoe), whose top-k gates are renormalised to
+    # sum to 1 and then multiplied by ``route_scale``.
+    score_func: str = "softmax"
+    route_scale: float = 1.0
+    # afmoe: a per-expert bias added to the scores for choosing the top-k
+    # only; the gates stay the unbiased scores.
+    selection_bias: bool = False
+    # Shared experts every token runs beside its routed ones, as one gated
+    # MLP of width shared_experts * expert width.
+    shared_experts: int = 0
+    # Routed expert width (0 -> ModelConfig.d_ff); ModelConfig.d_ff is then
+    # the width of the leading ``first_dense`` layers' dense MLP.
+    expert_ff: int = 0
+    first_dense: int = 0
+    # Expert share (expert parallelism): this chip holds experts
+    # [first_held, first_held + held_experts) of every MoE layer.  The
+    # router still scores all num_experts; the layer computes the held
+    # experts' part of the result, and at inference runs only the (token,
+    # held expert) pairs, sorted by expert, through kernels/expert_ffn.
+    # 0 = every expert, through the (G, n, E, C) dispatch.
+    held_experts: int = 0
+    first_held: int = 0
+
+    @property
+    def held(self) -> int:
+        """Experts whose weights this chip holds."""
+        return self.held_experts or self.num_experts
 
 
 @dataclass(frozen=True)
@@ -59,6 +87,10 @@ class AttentionConfig:
     rope_theta_global: float = 0.0  # gemma3 uses a larger theta on global layers
     logit_softcap: float = 0.0
     qk_norm: bool = False
+    # afmoe: attention output scaled by sigmoid(x Wgate) per head dim
+    output_gate: bool = False
+    # afmoe: global (full-attention) layers take no position embedding
+    nope_global: bool = False
 
 
 @dataclass(frozen=True)
@@ -88,6 +120,9 @@ class ModelConfig:
     param_dtype: str = "float32"
     # hybrid (hymba): attention and mamba run in parallel in every block
     hybrid_parallel: bool = True
+    # afmoe: the attention and MLP outputs are each RMS-normed before they
+    # join the residual (four norms a layer)
+    post_norms: bool = False
 
     @property
     def head_dim(self) -> int:
@@ -117,14 +152,20 @@ class ModelConfig:
         out = 0 if self.tie_embeddings else self.vocab_size * self.d_model
         att = self.d_model * hd * (a.num_heads + 2 * a.num_kv_heads) \
             + a.num_heads * hd * self.d_model
+        if a.output_gate:
+            att += self.d_model * a.num_heads * hd
         nmat = 3 if self.gated_mlp else 2
+        dense_prefix = 0
         if self.family == "moe":
             m = self.moe
-            ff_exp = nmat * self.d_model * self.d_ff * m.num_experts
+            f_exp = m.expert_ff or self.d_ff
+            ff_exp = nmat * self.d_model * f_exp * (m.held + m.shared_experts)
             ff_dense = (nmat * self.d_model * m.dense_residual_ff
                         if m.dense_residual else 0)
             router = self.d_model * m.num_experts
             ff = ff_exp + ff_dense + router
+            dense_prefix = m.first_dense * (
+                nmat * self.d_model * self.d_ff - ff)
         elif self.family == "ssm":
             # xlstm: inner projections replace FFN; approximate with expand factor
             inner = self.ssm.expand * self.d_model
@@ -136,7 +177,9 @@ class ModelConfig:
             inner = self.ssm.expand * self.d_model
             ff += 2 * self.d_model * inner + inner * self.d_model
         layers = self.num_layers + self.encoder_layers
-        return emb + out + layers * (att + ff + 2 * self.d_model) + self.d_model
+        norms = (4 if self.post_norms else 2) * self.d_model
+        return (emb + out + layers * (att + ff + norms) + dense_prefix
+                + self.d_model)
 
     def active_param_count(self) -> int:
         """Params touched per token (MoE: top_k experts only)."""
@@ -145,9 +188,11 @@ class ModelConfig:
         m = self.moe
         full = self.param_count()
         nmat = 3 if self.gated_mlp else 2
-        ff_all = nmat * self.d_model * self.d_ff * m.num_experts * self.num_layers
-        ff_act = nmat * self.d_model * self.d_ff * m.top_k * self.num_layers
-        return full - ff_all + ff_act
+        per_expert = nmat * self.d_model * (m.expert_ff or self.d_ff)
+        moe_layers = self.num_layers - m.first_dense
+        # the chip's share of each token's top_k experts
+        active = m.top_k * m.held / m.num_experts
+        return int(full - per_expert * moe_layers * (m.held - active))
 
 
 # ---------------------------------------------------------------------------
@@ -393,7 +438,10 @@ def reduced(cfg: ModelConfig) -> ModelConfig:
     moe = cfg.moe
     if moe.num_experts:
         moe = replace(moe, num_experts=4, top_k=min(2, moe.top_k),
-                      dense_residual_ff=64 if moe.dense_residual else 0)
+                      dense_residual_ff=64 if moe.dense_residual else 0,
+                      expert_ff=32 if moe.expert_ff else 0,
+                      first_dense=min(moe.first_dense, 1),
+                      held_experts=min(moe.held_experts, 2), first_held=0)
     ssm = replace(cfg.ssm, state_size=min(cfg.ssm.state_size, 8), num_heads=2)
     return replace(
         cfg,

@@ -30,7 +30,8 @@ NEG_INF = -2.0**30   # large-negative for masking (safe in bf16 after cast)
 # ---------------------------------------------------------------------------
 
 def attention_init(rng, d_model: int, num_heads: int, num_kv_heads: int,
-                   head_dim: int, qk_norm: bool = False) -> dict:
+                   head_dim: int, qk_norm: bool = False,
+                   output_gate: bool = False) -> dict:
     r = jax.random.split(rng, 4)
     p = {
         "wq": dense_init(r[0], d_model, num_heads, head_dim),
@@ -42,16 +43,22 @@ def attention_init(rng, d_model: int, num_heads: int, num_kv_heads: int,
     if qk_norm:
         p["q_norm"] = {"scale": jnp.zeros((head_dim,), jnp.float32)}
         p["k_norm"] = {"scale": jnp.zeros((head_dim,), jnp.float32)}
+    if output_gate:
+        p["wgate"] = dense_init(jax.random.fold_in(rng, 4), d_model,
+                                num_heads, head_dim)
     return p
 
 
 def qkv_project(params: dict, x: jax.Array, *, num_kv_heads: int,
                 positions: jax.Array, theta, qk_norm: bool,
-                eps: float, dp=None, kv_input: jax.Array | None = None):
+                eps: float, dp=None, kv_input: jax.Array | None = None,
+                rope=None):
     """Project to q, k, v (with RoPE + optional qk-norm applied).
 
     ``kv_input`` (cross-attention) routes k/v projections off a different
-    sequence (encoder output); positions then only rotate q."""
+    sequence (encoder output); positions then only rotate q.  ``rope``, a
+    (traced) per-layer flag, keeps q and k unrotated where it is false:
+    a layer without position embedding inside a scanned stack."""
     xkv = x if kv_input is None else kv_input
     q = jnp.einsum("bsd,dhe->bshe", x, params["wq"].astype(x.dtype))
     k = jnp.einsum("bsd,dhe->bshe", xkv, params["wk"].astype(x.dtype))
@@ -61,13 +68,23 @@ def qkv_project(params: dict, x: jax.Array, *, num_kv_heads: int,
         k = rmsnorm(params["k_norm"], k, eps)
     if theta is not None:
         from repro.layers.rope import apply_rope
-        q = apply_rope(q, positions, theta)
-        if kv_input is None:
-            k = apply_rope(k, positions, theta)
+        qr = apply_rope(q, positions, theta)
+        kr = apply_rope(k, positions, theta) if kv_input is None else k
+        if rope is None:
+            q, k = qr, kr
+        else:
+            q, k = jnp.where(rope, qr, q), jnp.where(rope, kr, k)
     q = constrain(dp, q, ("batch", "seq", "heads", "head_dim"), tag="attn/q")
     k = constrain(dp, k, ("batch", "seq", "kv_heads", "head_dim"), tag="attn/k")
     v = constrain(dp, v, ("batch", "seq", "kv_heads", "head_dim"), tag="attn/v")
     return q, k, v
+
+
+def output_gate(params: dict, x: jax.Array, o: jax.Array) -> jax.Array:
+    """afmoe's gated attention: ``o * sigmoid(x Wgate)``, x the layer's
+    normed input (B, S, D), o the attention output (B, S, H, hd)."""
+    g = jnp.einsum("bsd,dhe->bshe", x, params["wgate"].astype(x.dtype))
+    return o * jax.nn.sigmoid(g).astype(o.dtype)
 
 
 def output_project(params: dict, o: jax.Array, dp=None) -> jax.Array:
@@ -338,6 +355,7 @@ def attend(q, k, v, *, q_pos, k_pos, causal: bool = True, window=None,
 
 
 __all__ = [
-    "attention_init", "qkv_project", "output_project", "make_mask",
+    "attention_init", "qkv_project", "output_gate", "output_project",
+    "make_mask",
     "attend", "attend_naive", "attend_flash", "NEG_INF",
 ]

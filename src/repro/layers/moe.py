@@ -18,7 +18,21 @@ axis.  The G↔E resharding between dispatch and expert compute is the EP
 all-to-all, materialized by GSPMD from the constraints this module issues.
 
 Arctic-style ``dense_residual``: a dense MLP runs in parallel and its
-output is added to the expert output.
+output is added to the expert output.  afmoe-style ``shared_experts``:
+one gated MLP every token runs, added the same way.
+
+**Expert share** (``MoEConfig.held_experts``, expert parallelism): the
+layer holds only experts ``[first_held, first_held + held)``.  The router
+scores all ``num_experts`` and picks each token's top-k among all of
+them; the layer computes the part of the result its own experts give,
+and assignments to experts held elsewhere add nothing here (on a
+deployment their part arrives over the EP exchange, which one chip does
+not have).  At inference (:func:`expert_share`) only the (token, held
+expert) pairs are computed: they are counted by expert, laid out sorted
+by expert with each group on whole row tiles, run through the grouped
+kernel (kernels/expert_ffn), and combined with their gates.  Each row's
+output depends on its own token alone, so outputs stay invariant to
+batch composition.
 """
 
 from __future__ import annotations
@@ -27,23 +41,37 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import MoEConfig
+from repro.kernels.expert_ffn import (
+    expert_ffn,
+    group_starts,
+    num_tiles,
+    tile_rows,
+)
 from repro.layers.common import act_fn, constrain, dense_init
+from repro.layers.mlp import mlp, mlp_init
 
 
 def moe_init(rng, d_model: int, d_ff: int, cfg: MoEConfig,
              gated: bool = True) -> dict:
+    """Router over all ``num_experts``; expert weights (E, D, F) / (E, F,
+    D) for the ``cfg.held`` experts this chip holds, of width
+    ``cfg.expert_ff or d_ff``."""
     r = jax.random.split(rng, 5)
-    e = cfg.num_experts
+    e, f = cfg.held, cfg.expert_ff or d_ff
     p = {
-        "router": dense_init(r[0], d_model, e, scale=1e-2),
-        "wi": dense_init(r[1], d_model, e, d_ff).transpose(1, 0, 2),  # (E,D,F)
-        "wo": dense_init(r[2], d_ff, e, d_model).transpose(1, 0, 2),  # (E,F,D)
+        "router": dense_init(r[0], d_model, cfg.num_experts, scale=1e-2),
+        "wi": dense_init(r[1], d_model, e, f).transpose(1, 0, 2),  # (E,D,F)
+        "wo": dense_init(r[2], f, e, d_model).transpose(1, 0, 2),  # (E,F,D)
     }
     if gated:
-        p["wg"] = dense_init(r[3], d_model, e, d_ff).transpose(1, 0, 2)
+        p["wg"] = dense_init(r[3], d_model, e, f).transpose(1, 0, 2)
     if cfg.dense_residual:
-        from repro.layers.mlp import mlp_init
         p["dense"] = mlp_init(r[4], d_model, cfg.dense_residual_ff, gated)
+    if cfg.selection_bias:
+        p["bias"] = jnp.zeros((cfg.num_experts,), jnp.float32)
+    if cfg.shared_experts:
+        p["shared"] = mlp_init(jax.random.fold_in(rng, 5), d_model,
+                               cfg.shared_experts * f, gated)
     return p
 
 
@@ -54,14 +82,24 @@ def _capacity(group: int, cfg: MoEConfig) -> int:
 
 def route(params: dict, x2d: jax.Array, cfg: MoEConfig, *,
           train: bool, rng=None):
-    """Router: top-k gates + aux losses. x2d: (T, D) flat tokens."""
+    """Router: top-k gates + aux losses. x2d: (T, D) flat tokens.  ``idx``
+    (T, k) names experts of all ``num_experts``."""
     logits = jnp.einsum("td,de->te", x2d.astype(jnp.float32),
                         params["router"].astype(jnp.float32))
     if train and cfg.router_jitter > 0 and rng is not None:
         logits += cfg.router_jitter * jax.random.normal(rng, logits.shape)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, idx = jax.lax.top_k(probs, cfg.top_k)              # (T,k)
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    if cfg.score_func == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        choose = scores + params["bias"] if "bias" in params else scores
+        _, idx = jax.lax.top_k(choose, cfg.top_k)             # (T,k)
+        gates = jnp.take_along_axis(scores, idx, axis=-1)
+        gates = gates / (gates.sum(-1, keepdims=True) + 1e-20) \
+            * cfg.route_scale
+        probs = scores / scores.sum(-1, keepdims=True)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
+        gates, idx = jax.lax.top_k(probs, cfg.top_k)          # (T,k)
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
     # aux losses (Switch-style)
     me = probs.mean(axis=0)                                    # (E,)
     ce = jnp.zeros_like(me).at[idx[:, 0]].add(1.0) / idx.shape[0]
@@ -89,15 +127,17 @@ def moe(params: dict, x: jax.Array, cfg: MoEConfig, *, act: str = "silu",
     while tokens % g_sz:
         g_sz -= 1
     g = tokens // g_sz
-    e = cfg.num_experts
+    e = cfg.held
     c = _capacity(g_sz, cfg) if train else g_sz * cfg.top_k
 
     xf = x.reshape(tokens, d)
     gates, idx, aux = route(params, xf, cfg, train=train, rng=rng)
 
-    # block-local positions in each expert queue
-    onehot = jax.nn.one_hot(idx.reshape(g, g_sz, cfg.top_k), e,
-                            dtype=jnp.int32)                   # (G,n,k,E)
+    # block-local positions in each held expert's queue (an assignment to
+    # an expert held elsewhere is an all-zero one-hot row: it takes no
+    # queue slot and adds nothing)
+    onehot = jax.nn.one_hot(idx.reshape(g, g_sz, cfg.top_k) - cfg.first_held,
+                            e, dtype=jnp.int32)                # (G,n,k,E)
     flat = onehot.reshape(g, g_sz * cfg.top_k, e)
     pos = jnp.cumsum(flat, axis=1) - 1                         # (G,n*k,E)
     pos = pos.reshape(g, g_sz, cfg.top_k, e)
@@ -137,11 +177,57 @@ def moe(params: dict, x: jax.Array, cfg: MoEConfig, *, act: str = "silu",
     out = constrain(dp, out, ("batch", "seq", "embed"), tag="moe/out",
                     qos="moe-dispatch")
 
-    if "dense" in params:  # arctic dense residual
-        from repro.layers.mlp import mlp as dense_mlp
-        out = out + dense_mlp(params["dense"], x, act=act, dp=dp,
-                              tag="moe/dense_residual")
-    return out, aux
+    return _add_dense(params, x, out, act=act, dp=dp), aux
 
 
-__all__ = ["moe_init", "moe", "route"]
+def _add_dense(params: dict, x, out, *, act: str, dp):
+    """What every token runs besides its routed experts: arctic's dense
+    residual MLP, afmoe's shared experts."""
+    if "dense" in params:
+        out = out + mlp(params["dense"], x, act=act, dp=dp,
+                        tag="moe/dense_residual")
+    if "shared" in params:
+        out = out + mlp(params["shared"], x, act=act, dp=dp,
+                        tag="moe/shared")
+    return out
+
+
+def expert_share(params: dict, x: jax.Array, cfg: MoEConfig, *,
+                 act: str = "silu", dp=None):
+    """Inference through the held experts alone (module docstring).
+
+    x: (B, S, D).  Returns ``(out, rows)``: out (B, S, D) is the held
+    experts' part of the routed result plus what every token runs; rows
+    (held,) int32 counts the rows each held expert computed."""
+    b, s, d = x.shape
+    t, k, e = b * s, cfg.top_k, cfg.held
+    xf = x.reshape(t, d)
+    gates, idx, _ = route(params, xf, cfg, train=False)
+    local = idx.reshape(-1) - cfg.first_held                   # (T·k,)
+    held = (local >= 0) & (local < e)
+    onehot = jax.nn.one_hot(jnp.where(held, local, -1), e,
+                            dtype=jnp.int32)                   # (T·k, E)
+    rows = onehot.sum(0)
+    # counting sort: each pair's rank in its expert's group, and its row
+    # in the tile-padded, expert-sorted layout
+    rank = jnp.sum((jnp.cumsum(onehot, axis=0) - 1) * onehot, axis=1)
+    max_rows = t * min(k, e)
+    tm = tile_rows(max_rows)
+    m = num_tiles(max_rows, e, tm) * tm
+    dest = jnp.where(held, group_starts(rows, tm)[jnp.clip(local, 0, e - 1)]
+                     + rank, m)
+    xs = jnp.zeros((m, d), x.dtype).at[dest].set(
+        jnp.repeat(xf, k, axis=0), mode="drop")
+    ys = expert_ffn(xs, rows, params["wg"], params["wi"], params["wo"],
+                    tm=tm, act=act)
+    # rows of ys outside the groups hold nothing: select, never multiply
+    y = jnp.where(held[:, None],
+                  ys[jnp.minimum(dest, m - 1)].astype(jnp.float32)
+                  * gates.reshape(-1, 1), 0.0)
+    out = y.reshape(t, k, d).sum(1).astype(x.dtype).reshape(b, s, d)
+    out = constrain(dp, out, ("batch", "seq", "embed"), tag="moe/out",
+                    qos="moe-dispatch")
+    return _add_dense(params, x, out, act=act, dp=dp), rows
+
+
+__all__ = ["moe_init", "moe", "route", "expert_share"]

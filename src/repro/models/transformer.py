@@ -1,11 +1,15 @@
 """Decoder-only transformer LM covering the dense, MoE and VLM families
-(gemma3-4b/1b, granite-34b/3-2b, llava-next-34b, arctic-480b, grok-1-314b).
+(gemma3-4b/1b, granite-34b/3-2b, llava-next-34b, arctic-480b, grok-1-314b,
+trinity-mini).
 
 The layer stack is a single ``lax.scan`` over stacked per-layer params;
 per-layer heterogeneity (gemma3's 5:1 local:global pattern, per-layer RoPE
-theta) rides along as scanned flag arrays, so the traced HLO contains ONE
-layer body regardless of depth — which is what keeps 88-layer granite
-compilable at 512-way SPMD.
+theta, afmoe's RoPE-less global layers) rides along as scanned flag
+arrays, so the traced HLO contains ONE layer body regardless of depth —
+which is what keeps 88-layer granite compilable at 512-way SPMD.  An MoE
+model with leading dense layers (``MoEConfig.first_dense``) has two
+bodies, each one scan: the dense prefix (``params["dense_layers"]``),
+then the MoE layers (``params["layers"]``).
 
 All communication edges are issued through the CoRD dataplane (``dp``).
 """
@@ -24,6 +28,7 @@ from repro.layers.attention import (
     attend,
     attend_naive,
     attention_init,
+    output_gate,
     output_project,
     qkv_project,
 )
@@ -36,7 +41,7 @@ from repro.layers.kvcache import (
     slot_validity,
 )
 from repro.layers.mlp import mlp, mlp_init
-from repro.layers.moe import moe, moe_init
+from repro.layers.moe import expert_share, moe, moe_init
 from repro.models.losses import ce_metrics, chunked_ce_loss
 
 BIG_WINDOW = 0  # window value meaning "no window" in make_mask
@@ -67,6 +72,38 @@ def layer_flags(cfg: ModelConfig) -> tuple[np.ndarray, np.ndarray]:
     return window, theta
 
 
+def _per_layer(cfg: ModelConfig, *extra) -> tuple:
+    """The scanned per-layer arrays: window, theta, then ``extra`` (a
+    layer index, caches), then, where global layers take no position
+    embedding, the RoPE flag — appended last so that a model without it
+    scans exactly what it always did."""
+    window, theta = layer_flags(cfg)
+    xs = (jnp.asarray(window), jnp.asarray(theta), *extra)
+    if cfg.attention.nope_global:
+        xs += (jnp.asarray(window > 0),)
+    return xs
+
+
+def _scan_layers(body, carry, params, cfg: ModelConfig, xs: tuple,
+                 wrap=None):
+    """``lax.scan`` of ``body(carry, (lp, *xs_i))`` over the stack (through
+    ``wrap``, e.g. a remat policy, where given), ``xs`` the per-layer
+    arrays over all layers.  ``body`` returns ``(carry, (y, rows))``;
+    returns ``(carry, ys, rows)``, the ys of all layers joined in layer
+    order, ``rows`` those of the MoE layers."""
+    f = wrap(body) if wrap else body
+    nd = cfg.moe.first_dense if cfg.family == "moe" else 0
+    if not nd:
+        carry, (ys, rows) = jax.lax.scan(f, carry, (params["layers"], *xs))
+        return carry, ys, rows
+    carry, (ys0, _) = jax.lax.scan(f, carry, (params["dense_layers"],
+                                              *(a[:nd] for a in xs)))
+    carry, (ys1, rows) = jax.lax.scan(f, carry, (params["layers"],
+                                                 *(a[nd:] for a in xs)))
+    ys = jax.tree.map(lambda p, q: jnp.concatenate([p, q]), ys0, ys1)
+    return carry, ys, rows
+
+
 # ---------------------------------------------------------------------------
 # init
 # ---------------------------------------------------------------------------
@@ -75,16 +112,22 @@ def transformer_init(rng, cfg: ModelConfig) -> dict:
     a = cfg.attention
     r = jax.random.split(rng, 4)
 
-    def one_layer(lr):
+    nd = cfg.moe.first_dense if cfg.family == "moe" else 0
+
+    def one_layer(lr, dense=False):
         ks = jax.random.split(lr, 2)
         p = {
             "norm1": rmsnorm_init(cfg.d_model),
             "norm2": rmsnorm_init(cfg.d_model),
             "attn": attention_init(ks[0], cfg.d_model, a.num_heads,
                                    a.num_kv_heads, cfg.head_dim,
-                                   qk_norm=a.qk_norm),
+                                   qk_norm=a.qk_norm,
+                                   output_gate=a.output_gate),
         }
-        if cfg.family == "moe":
+        if cfg.post_norms:
+            p["post_attn_norm"] = rmsnorm_init(cfg.d_model)
+            p["post_mlp_norm"] = rmsnorm_init(cfg.d_model)
+        if cfg.family == "moe" and not dense:
             p["moe"] = moe_init(ks[1], cfg.d_model, cfg.d_ff, cfg.moe,
                                 gated=cfg.gated_mlp)
         else:
@@ -95,9 +138,12 @@ def transformer_init(rng, cfg: ModelConfig) -> dict:
     params = {
         "embed": embedding_init(r[0], cfg.vocab_size, cfg.d_model,
                                 tied=cfg.tie_embeddings),
-        "layers": stacked_init(r[1], cfg.num_layers, one_layer),
+        "layers": stacked_init(r[1], cfg.num_layers - nd, one_layer),
         "final_norm": rmsnorm_init(cfg.d_model),
     }
+    if nd:
+        params["dense_layers"] = stacked_init(r[3], nd,
+                                              partial(one_layer, dense=True))
     if cfg.family == "vlm":
         params["vision_proj"] = dense_init(r[2], cfg.frontend_dim, cfg.d_model)
     return params
@@ -110,12 +156,16 @@ def transformer_init(rng, cfg: ModelConfig) -> dict:
 def _layer(lp, x, *, cfg, dp, positions, window, theta, mode,
            cache_k=None, cache_v=None, cache_pos=None, tables=None,
            layer=None, kv_len=None, train=False, impl="flash", q_block=512,
-           kv_block=1024):
+           kv_block=1024, rope=None):
+    """One layer.  Returns ``(x, aux, new_ck, new_cv, rows)``: ``rows``
+    (held,) int32 counts the rows each held expert computed where the
+    layer runs through its expert share (``expert_share``), else None."""
     a = cfg.attention
     h = rmsnorm(lp["norm1"], x, cfg.norm_eps)
     q, k, v = qkv_project(lp["attn"], h, num_kv_heads=a.num_kv_heads,
                           positions=positions, theta=theta,
-                          qk_norm=a.qk_norm, eps=cfg.norm_eps, dp=dp)
+                          qk_norm=a.qk_norm, eps=cfg.norm_eps, dp=dp,
+                          rope=rope)
     aux = jnp.zeros((), jnp.float32)
     if mode == "train":
         o = attend(q, k, v, q_pos=positions, k_pos=positions,
@@ -201,17 +251,27 @@ def _layer(lp, x, *, cfg, dp, positions, window, theta, mode,
                    window=window, logit_cap=a.logit_softcap, k_valid=k_valid,
                    impl="flash", q_block=1, kv_block=kv_block)
         new_ck, new_cv = cache_k, cache_v
-    x = x + output_project(lp["attn"], o, dp=dp)
+    if "wgate" in lp["attn"]:
+        o = output_gate(lp["attn"], h, o)
+    o = output_project(lp["attn"], o, dp=dp)
+    if "post_attn_norm" in lp:
+        o = rmsnorm(lp["post_attn_norm"], o, cfg.norm_eps)
+    x = x + o
 
     h = rmsnorm(lp["norm2"], x, cfg.norm_eps)
-    if cfg.family == "moe":
+    rows = None
+    if "moe" not in lp:
+        f = mlp(lp["mlp"], h, act=cfg.act_fn, dp=dp)
+    elif cfg.moe.held_experts and not train:
+        f, rows = expert_share(lp["moe"], h, cfg.moe, act=cfg.act_fn, dp=dp)
+    else:
         f, aux = moe(lp["moe"], h, cfg.moe, act=cfg.act_fn, train=train,
                      dp=dp)
-    else:
-        f = mlp(lp["mlp"], h, act=cfg.act_fn, dp=dp)
+    if "post_mlp_norm" in lp:
+        f = rmsnorm(lp["post_mlp_norm"], f, cfg.norm_eps)
     x = x + f
     x = constrain(dp, x, ("batch", "seq_resid", "embed"), tag="layer/out")
-    return x, aux, new_ck, new_cv
+    return x, aux, new_ck, new_cv, rows
 
 
 # ---------------------------------------------------------------------------
@@ -236,34 +296,36 @@ def transformer_apply(params, cfg: ModelConfig, batch: dict, *, dp=None,
         s = s + prefix
     positions = jnp.arange(s, dtype=jnp.int32)
 
-    window_arr, theta_arr = layer_flags(cfg)
     mode = "prefill" if cache is not None else "train"
 
     def body(carry, xs):
         x, aux = carry
         if cache is not None:
-            lp, w, th, ck, cv = xs
+            lp, w, th, ck, cv, *rope = xs
         else:
-            lp, w, th = xs
+            lp, w, th, *rope = xs
             ck = cv = None
-        x, a, ck, cv = _layer(lp, x, cfg=cfg, dp=dp, positions=positions,
-                              window=w, theta=th, mode=mode, cache_k=ck,
-                              cache_v=cv, train=train, impl=impl,
-                              q_block=q_block, kv_block=kv_block)
+        x, a, ck, cv, rows = _layer(lp, x, cfg=cfg, dp=dp,
+                                    positions=positions, window=w, theta=th,
+                                    mode=mode, cache_k=ck, cache_v=cv,
+                                    train=train, impl=impl, q_block=q_block,
+                                    kv_block=kv_block,
+                                    rope=rope[0] if rope else None)
         out = (ck, cv) if cache is not None else None
-        return (x, aux + a), out
+        return (x, aux + a), (out, rows)
 
+    wrap = None
     if remat == "full":
-        body = jax.checkpoint(body, prevent_cse=False)
+        wrap = partial(jax.checkpoint, prevent_cse=False)
     elif remat == "dots":
-        body = jax.checkpoint(
-            body, policy=jax.checkpoint_policies.checkpoint_dots,
-            prevent_cse=False)
+        wrap = partial(jax.checkpoint,
+                       policy=jax.checkpoint_policies.checkpoint_dots,
+                       prevent_cse=False)
 
-    xs = (params["layers"], jnp.asarray(window_arr), jnp.asarray(theta_arr))
-    if cache is not None:
-        xs = xs + (cache["k"], cache["v"])
-    (x, aux), caches = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)), xs)
+    xs = _per_layer(cfg, *((cache["k"], cache["v"]) if cache is not None
+                           else ()))
+    (x, aux), caches, _ = _scan_layers(body, (x, jnp.zeros((), jnp.float32)),
+                                       params, cfg, xs, wrap=wrap)
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     new_cache = None
     if cache is not None:
@@ -335,19 +397,19 @@ def transformer_prefill_chunk(params, cfg: ModelConfig, batch: dict, cache,
     offset = jnp.asarray(offset, jnp.int32)
     x = embed(params["embed"], tokens, dtype, dp=dp)
     positions = offset + jnp.arange(c, dtype=jnp.int32)
-    window_arr, theta_arr = layer_flags(cfg)
 
     def body(x, xs):
-        lp, w, th, ck, cv = xs
-        x, _aux, ck, cv = _layer(lp, x, cfg=cfg, dp=dp, positions=positions,
-                                 window=w, theta=th, mode="chunk",
-                                 cache_k=ck, cache_v=cv, cache_pos=offset,
-                                 kv_block=kv_block)
-        return x, (ck, cv)
+        lp, w, th, ck, cv, *rope = xs
+        x, _aux, ck, cv, rows = _layer(lp, x, cfg=cfg, dp=dp,
+                                       positions=positions, window=w,
+                                       theta=th, mode="chunk", cache_k=ck,
+                                       cache_v=cv, cache_pos=offset,
+                                       kv_block=kv_block,
+                                       rope=rope[0] if rope else None)
+        return x, ((ck, cv), rows)
 
-    xs = (params["layers"], jnp.asarray(window_arr), jnp.asarray(theta_arr),
-          cache["k"], cache["v"])
-    x, caches = jax.lax.scan(body, x, xs)
+    xs = _per_layer(cfg, cache["k"], cache["v"])
+    x, caches, _ = _scan_layers(body, x, params, cfg, xs)
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     from repro.layers.embedding import logits as logits_fn
     if last_pos is None:
@@ -367,19 +429,19 @@ def transformer_decode_step(params, cfg: ModelConfig, token, cache, pos, *,
     b = token.shape[0]
     x = embed(params["embed"], token, dtype, dp=dp)
     positions = jnp.full((1,), pos, jnp.int32)
-    window_arr, theta_arr = layer_flags(cfg)
 
     def body(x, xs):
-        lp, w, th, ck, cv = xs
-        x, _aux, ck, cv = _layer(lp, x, cfg=cfg, dp=dp, positions=positions,
-                                 window=w, theta=th, mode="decode",
-                                 cache_k=ck, cache_v=cv, cache_pos=pos,
-                                 kv_block=kv_block)
-        return x, (ck, cv)
+        lp, w, th, ck, cv, *rope = xs
+        x, _aux, ck, cv, rows = _layer(lp, x, cfg=cfg, dp=dp,
+                                       positions=positions, window=w,
+                                       theta=th, mode="decode", cache_k=ck,
+                                       cache_v=cv, cache_pos=pos,
+                                       kv_block=kv_block,
+                                       rope=rope[0] if rope else None)
+        return x, ((ck, cv), rows)
 
-    xs = (params["layers"], jnp.asarray(window_arr), jnp.asarray(theta_arr),
-          cache["k"], cache["v"])
-    x, caches = jax.lax.scan(body, x, xs)
+    xs = _per_layer(cfg, cache["k"], cache["v"])
+    x, caches, _ = _scan_layers(body, x, params, cfg, xs)
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     from repro.layers.embedding import logits as logits_fn
     return logits_fn(params["embed"], x, dp=dp), {"k": caches[0], "v": caches[1]}
@@ -400,18 +462,18 @@ def transformer_decode_step_slots(params, cfg: ModelConfig, token, cache,
     pos = jnp.asarray(pos, jnp.int32)
     x = embed(params["embed"], token, dtype, dp=dp)
     positions = pos[:, None]                       # (B, 1) per-slot RoPE
-    window_arr, theta_arr = layer_flags(cfg)
 
     def body(x, xs):
-        lp, w, th, ck, cv = xs
-        x, _aux, ck, cv = _layer(lp, x, cfg=cfg, dp=dp, positions=positions,
-                                 window=w, theta=th, mode="decode_slots",
-                                 cache_k=ck, cache_v=cv, cache_pos=pos)
-        return x, (ck, cv)
+        lp, w, th, ck, cv, *rope = xs
+        x, _aux, ck, cv, rows = _layer(lp, x, cfg=cfg, dp=dp,
+                                       positions=positions, window=w,
+                                       theta=th, mode="decode_slots",
+                                       cache_k=ck, cache_v=cv, cache_pos=pos,
+                                       rope=rope[0] if rope else None)
+        return x, ((ck, cv), rows)
 
-    xs = (params["layers"], jnp.asarray(window_arr), jnp.asarray(theta_arr),
-          cache["k"], cache["v"])
-    x, caches = jax.lax.scan(body, x, xs)
+    xs = _per_layer(cfg, cache["k"], cache["v"])
+    x, caches, _ = _scan_layers(body, x, params, cfg, xs)
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     from repro.layers.embedding import logits as logits_fn
     return logits_fn(params["embed"], x, dp=dp), {"k": caches[0], "v": caches[1]}
@@ -427,31 +489,36 @@ def transformer_decode_step_paged(params, cfg: ModelConfig, token, pool,
     int32, each slot's write position (the pool holds ``[0, pos)``).  Each
     layer attends each slot's live blocks straight from the pool
     (kernels/paged_attention) plus its new token, so nothing of the size
-    of the whole table is built.  Returns ``(logits, new)`` with ``new``
-    the appended token's {"k", "v"} of (layers, B, KVH, hd), which the
-    caller writes into the pool (``kv_pool_scatter_token``).  Shapes depend
-    only on the slot and pool geometry: one compile per engine."""
+    of the whole table is built.  Returns ``(logits, new, rows)`` with
+    ``new`` the appended token's {"k", "v"} of (layers, B, KVH, hd), which
+    the caller writes into the pool (``kv_pool_scatter_token``), and
+    ``rows`` the (MoE layers, held experts) int32 rows each held expert
+    computed where the MoE layers run through their expert share, else
+    None.  Shapes depend only on the slot and pool geometry: one compile
+    per engine."""
     dtype = dtype_of(cfg.dtype)
     pos = jnp.asarray(pos, jnp.int32)
     tables = jnp.asarray(tables, jnp.int32)
     x = embed(params["embed"], token, dtype, dp=dp)
     positions = pos[:, None]                       # (B, 1) per-slot RoPE
-    window_arr, theta_arr = layer_flags(cfg)
 
     def body(x, xs):
-        lp, w, th, layer = xs
-        x, _aux, nk, nv = _layer(lp, x, cfg=cfg, dp=dp, positions=positions,
-                                 window=w, theta=th, mode="decode_paged",
-                                 cache_k=pool["k"], cache_v=pool["v"],
-                                 cache_pos=pos, tables=tables, layer=layer)
-        return x, (nk, nv)
+        lp, w, th, layer, *rope = xs
+        x, _aux, nk, nv, rows = _layer(lp, x, cfg=cfg, dp=dp,
+                                       positions=positions, window=w,
+                                       theta=th, mode="decode_paged",
+                                       cache_k=pool["k"], cache_v=pool["v"],
+                                       cache_pos=pos, tables=tables,
+                                       layer=layer,
+                                       rope=rope[0] if rope else None)
+        return x, ((nk, nv), rows)
 
-    xs = (params["layers"], jnp.asarray(window_arr), jnp.asarray(theta_arr),
-          jnp.arange(cfg.num_layers, dtype=jnp.int32))
-    x, new = jax.lax.scan(body, x, xs)
+    xs = _per_layer(cfg, jnp.arange(cfg.num_layers, dtype=jnp.int32))
+    x, new, rows = _scan_layers(body, x, params, cfg, xs)
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     from repro.layers.embedding import logits as logits_fn
-    return logits_fn(params["embed"], x, dp=dp), {"k": new[0], "v": new[1]}
+    return (logits_fn(params["embed"], x, dp=dp), {"k": new[0], "v": new[1]},
+            rows)
 
 
 __all__ = [

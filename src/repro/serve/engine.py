@@ -305,11 +305,13 @@ class Engine:
             def serve_decode(p, t, pool, tables, pos, act):
                 # each slot's blocks are read in place; an inactive slot
                 # reads none (position 0), its logits row is never read
-                # and its token write puts back what is there
-                logits, new = step_paged(p, t, pool, tables,
-                                         jnp.where(act, pos, 0), dp=dp)
+                # and its token write puts back what is there.  ``rows``:
+                # an expert share's rows per MoE layer and held expert
+                # (None without one)
+                logits, new, rows = step_paged(p, t, pool, tables,
+                                               jnp.where(act, pos, 0), dp=dp)
                 return logits, kv_pool_scatter_token(pool, new, tables,
-                                                     pos, act, bs)
+                                                     pos, act, bs), rows
 
             def serve_pool_insert(pool, pc, ids):
                 return kv_pool_insert(pool, pc, ids, bs)
@@ -361,6 +363,10 @@ class Engine:
             lambda: {"requests": 0, "tokens": 0, "deferrals": 0,
                      "wfq_grants": 0, "occupancy_steps": 0,
                      "preemptions": 0, "restores": 0})
+        # running totals over the paged decode ticks of a model whose MoE
+        # layers run through an expert share: rows its held experts
+        # computed, and (layer, held expert) pairs that had any
+        self.moe_stats = {"ticks": 0, "moe_rows": 0, "moe_experts_hit": 0}
         self._tenant_ids: dict[str, int] = {}
 
     def _tenant_id(self, tenant: str) -> int:
@@ -923,9 +929,10 @@ class Engine:
         if self.paged:                   # the share of the tables readable
             span["blocks"] = sum(len(self._slot_blocks[i]) for i in active)
             span["table_blocks"] = self._tables.size
+        rows = None
         with TraceAnnotation("serve/decode", **span):
             if self.paged:
-                logits, cache = self._step_pool(
+                logits, cache, rows = self._step_pool(
                     self.params, jnp.asarray(tok), cache,
                     jnp.asarray(self._tables), jnp.asarray(vecs["pos"]),
                     jnp.asarray(vecs["active"]))
@@ -933,9 +940,19 @@ class Engine:
                 logits, cache = self._step_slots(self.params,
                                                  jnp.asarray(tok), cache,
                                                  jnp.asarray(vecs["pos"]))
-        with TraceAnnotation("serve/sample"):
+        with TraceAnnotation("serve/sample") as sample_span:
             rng, k = jax.random.split(rng)
-            nxt = np.asarray(sample(logits[:, -1, :], k, scfg.temperature))
+            # the expert rows, where the step has them, come back with
+            # the sampled tokens in one transfer
+            nxt, rows = jax.device_get(
+                (sample(logits[:, -1, :], k, scfg.temperature), rows))
+            if rows is not None:
+                moe = {"moe_rows": int(rows.sum()),
+                       "moe_experts_hit": int((rows > 0).sum())}
+                sample_span.set_metadata(**moe)
+                self.moe_stats["ticks"] += 1
+                for name, v in moe.items():
+                    self.moe_stats[name] += v
         with TraceAnnotation("serve/emit", tokens=len(active)):
             for i in active:
                 r = slots[i]

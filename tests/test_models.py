@@ -130,3 +130,44 @@ def test_long_context_flags():
     for arch in ARCHS:
         cfg = get_model_config(arch)
         assert cfg.is_subquadratic == (arch in LONG_CONTEXT_ARCHS), arch
+
+
+def _trinity_requests(lengths, max_new=5):
+    from repro.serve import Request
+    return [Request(rid=i, prompt=np.asarray((np.arange(n) + 3 * i) % 97,
+                                             np.int32),
+                    max_new_tokens=max_new) for i, n in enumerate(lengths)]
+
+
+def test_trinity_serves_on_the_normal_path():
+    """trinity-mini's smoke preset (a dense layer, then MoE layers at an
+    expert share of 2 held of 4, sliding and RoPE-less full attention)
+    through build_model and the paged, chunk-prefilling engine: every
+    request gets its tokens, identical to whole prefill on the stripe
+    layout, and continuous batching equals gang decode at temperature 0
+    (the expert layer's outputs do not depend on batch composition)."""
+    from repro.configs.base import ServeConfig
+    from repro.serve import Engine
+    cfg = get_model_config("trinity-mini", smoke=True)
+    assert cfg.moe.held_experts == 2 and cfg.moe.first_dense == 1
+    model = build_model(cfg)
+    params = model.init(RNG)
+    base = dict(max_batch=2, max_new_tokens=5, kv_cache_len=128)
+
+    def out(eng, reqs, **kw):
+        return {r.rid: list(r.out_tokens) for r in eng.run(reqs, **kw)}
+
+    paged = Engine(model, params, cfg,
+                   ServeConfig(**base, prefill_chunk=16, block_size=8),
+                   eos_id=-1)
+    assert paged.paged and paged.chunked
+    mixed = [8, 20, 40]
+    got = out(paged, _trinity_requests(mixed))
+    assert all(len(v) == 5 for v in got.values())
+    assert paged.moe_stats["ticks"] > 0 and paged.moe_stats["moe_rows"] > 0
+    stripe = Engine(model, params, cfg, ServeConfig(**base), eos_id=-1)
+    assert out(stripe, _trinity_requests(mixed)) == got
+    gang = Engine(model, params, cfg, ServeConfig(**base), eos_id=-1)
+    same = [8] * 5
+    assert out(paged, _trinity_requests(same)) == \
+        out(gang, _trinity_requests(same), scheduler="gang")

@@ -132,7 +132,8 @@ def test_paged_step_matches_gather_dense_scatter(smoke_model):
 
     @jax.jit
     def paged(params, tok, pool):
-        logits, new = model.decode_step_paged(params, tok, pool, tables, pos)
+        logits, new, _ = model.decode_step_paged(params, tok, pool, tables,
+                                                 pos)
         return logits, kv_pool_scatter_token(pool, new, tables, pos, active,
                                              bs)
 

@@ -191,3 +191,39 @@ def test_on_tick_fires_once_per_tick(smoke_model, obs_every):
     assert [s for s, _ in seen] == list(range(1, steps["steps"] + 1))
     if timeline is not None:
         assert [n for _, n in seen] == [s // obs_every for s, _ in seen]
+
+
+# ---------------------------------------------------------------------------
+# the expert share's rows on the decode span
+# ---------------------------------------------------------------------------
+
+def test_decode_span_carries_expert_rows(tmp_path):
+    """With MoE layers at an expert share, the rows its held experts
+    computed in each paged decode step (``moe_rows``) and the (layer,
+    held expert) pairs that had any (``moe_experts_hit``), summed over
+    layers, arrive with the sampled tokens: the tick's ``serve/sample``
+    span carries them, one per ``serve/decode`` span, and they add up to
+    the engine's running totals."""
+    cfg = get_model_config("trinity-mini", smoke=True)
+    model = build_model(cfg)
+    eng = Engine(model, model.init(jax.random.PRNGKey(0)), cfg,
+                 ServeConfig(**PAGED_CHUNKED), eos_id=-1)
+    with jax.profiler.trace(str(tmp_path)):
+        eng.run(_requests())
+    path = max(glob.glob(os.path.join(tmp_path, "**", "*.xplane.pb"),
+                         recursive=True), key=os.path.getmtime)
+    lines = _host_events(path)
+    decode = sorted((ev for ev in _spans(lines) if ev[2] == "serve/decode"),
+                    key=lambda ev: ev[0])
+    sample = sorted((ev for ev in _spans(lines) if ev[2] == "serve/sample"),
+                    key=lambda ev: ev[0])
+    assert len(decode) == len(sample) == eng.moe_stats["ticks"] > 0
+    moe_layers = cfg.num_layers - cfg.moe.first_dense
+    for (d0, d1, _, dst), (s0, _, _, st) in zip(decode, sample):
+        assert d1 <= s0                   # the sample follows its step
+        assert "moe_rows" not in dst
+        assert 0 < st["moe_experts_hit"] <= moe_layers * cfg.moe.held
+        assert st["moe_experts_hit"] <= st["moe_rows"] <= \
+            moe_layers * dst["active"] * cfg.moe.top_k
+    for name in ("moe_rows", "moe_experts_hit"):
+        assert sum(st[name] for *_, st in sample) == eng.moe_stats[name]

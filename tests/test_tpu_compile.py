@@ -5,7 +5,8 @@ Nothing here runs: each test lowers a kernel against a *described*
 refuses what interpret mode accepts (scalar stores to VMEM, slices not
 aligned to the tiling, ragged DMAs).  The payloads are the ones the
 served granite-3-2b path and the calibration probe hand the dataplane
-kernels, and the paged decode attention at granite-3-2b's widths.
+kernels, the paged decode attention at granite-3-2b's widths, and
+trinity-mini's grouped expert kernel and whole paged decode step.
 
 The topology is described only inside the module fixture — never while
 a module is imported — because only one process at a time may load the
@@ -20,8 +21,13 @@ import pytest
 from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import get_model_config
+from repro.configs.base import ServeConfig
 from repro.kernels.dataplane import bounce_copy, mediated_cost
+from repro.kernels.expert_ffn import expert_ffn, num_tiles, tile_rows
 from repro.kernels.paged_attention import paged_decode_attention
+from repro.models import build_model
+from repro.serve import Engine
 
 PAYLOADS = [
     pytest.param((4, 1, 2048), jnp.bfloat16, id="decode-activation"),
@@ -88,3 +94,55 @@ def test_paged_attention_compiles_for_v5e(one_chip):
         arg((b, h, hd), jnp.bfloat16), pool, pool, arg((b, t_len), i32),
         arg((b,), i32), new, new, arg((), i32), arg((), i32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("max_rows", [
+    pytest.param(16 * 8, id="decode-batch16"),
+    pytest.param(512 * 8, id="prefill-chunk512"),
+])
+def test_expert_ffn_compiles_for_v5e(one_chip, max_rows):
+    """trinity-mini's held experts (8 of width 1024 over hidden 2048) for
+    a decode tick of 16 slots and a prefill chunk of 512 tokens, top-8."""
+    e, d, f = 8, 2048, 1024
+    tm = tile_rows(max_rows)
+    m = num_tiles(max_rows, e, tm) * tm
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(lambda x, r, wg, wi, wo: expert_ffn(
+        x, r, wg, wi, wo, tm=tm, interpret=False)).lower(
+        arg((m, d)), arg((e,), jnp.int32), arg((e, d, f)), arg((e, d, f)),
+        arg((e, f, d))).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_trinity_paged_decode_step_compiles_for_v5e(one_chip):
+    """The engine's whole paged decode program for trinity-mini at its
+    published widths and the bench cell's geometry (16 slots of 8192,
+    4096 blocks of 16): both kernels inside the layer scans, and it fits
+    one chip's 15.75 GiB with the weights and the pool as arguments."""
+    cfg = get_model_config("trinity-mini")
+    model = build_model(cfg)
+    b, nb, bs = 16, 4096, 16
+    serve = ServeConfig(max_batch=b, prefill_chunk=512, max_new_tokens=512,
+                        kv_cache_len=8192, block_size=bs, n_blocks=nb)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(lambda s: arg(s.shape, jnp.bfloat16),
+                          jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    eng = Engine(model, params, cfg, serve, eos_id=-1)
+    layers, kvh, hd, dt = eng._pool_geom
+    pool = {n: arg((layers, nb + 1, bs, kvh * hd), dt) for n in "kv"}
+    i32 = jnp.int32
+    compiled = eng._step_pool.lower(
+        params, arg((b, 1), i32), pool, arg((b, eng._tables_len), i32),
+        arg((b,), i32), arg((b,), bool)).compile()
+    text = compiled.as_text()
+    assert "_expert_ffn_fwd" in text and "_paged_fwd" in text
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert used < 15.75 * 2**30
