@@ -29,14 +29,28 @@ is only ever moved, never computed on — availability is delayed by
 routing the chunk's first tile through a vector select on the delay
 token (the same ``tie`` trick as ``core/techniques.py``, in-kernel).
 
-Layout, as Mosaic requires it: the flat payload is viewed as
-``(n_chunks, rows, lanes)`` — lane-dense 128-wide rows whenever the
-chunk allows it — so every DMA moves one whole chunk between a leading
-index of the HBM view and a leading index of the ``(3, rows, lanes)``
-VMEM scratch; no DMA slices a tiled dimension.  A payload that is not a
-whole number of chunks is zero-padded outside the kernel and the
-padding sliced back off, so no DMA is ragged.  On TPU a chunk must be a
+Layout, as Mosaic requires it.  A payload whose trailing dims merge
+into a lane-aligned width (a multiple of 128), with leading dims left
+over, is taken in its own layout as a 2-D ``(rows, width)`` view — a
+bitcast of a row-major tiled array, so XLA relays nothing out around the
+call.  Its DMA blocks are the smallest tile-aligned run of rows holding
+``chunk_elems`` elements (``_block_rows``); rows past the last whole
+block are written into the kernel's output in place after it (a DMA
+cannot end at a partial tile), and a payload smaller than one block is
+a single whole-array block.  Every
+other payload (1-D verbs messages and collective buffers among them)
+takes the flat path: viewed as ``(n_chunks, rows, lanes)`` — lane-dense
+128-wide rows whenever the chunk allows it — so every DMA moves one
+whole chunk between leading indices of the HBM view and of the
+``(3, rows, lanes)`` VMEM scratch, no DMA slicing a tiled dimension; a
+payload that is not a whole number of chunks is zero-padded outside the
+kernel and the padding sliced back off.  On TPU a flat chunk must be a
 whole number of ``(sublanes, 128)`` tiles of its dtype (``_tile_elems``).
+
+The emulated cost does not depend on the view: the delay and the copy
+passes are accounted in ``chunk_elems``-element chunks
+(:func:`kernel_cost_totals`), and the kernel burns that total spread
+over whatever blocks it moves.
 
 ``interpret=True`` (selected automatically off-TPU, pattern per
 ``kernels/flash_attention``) runs the kernel body — including the DMAs
@@ -46,6 +60,7 @@ and semaphores — in the Pallas interpreter for validation on CPU.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -64,6 +79,10 @@ COST_COPIES = 1   # bounce passes made through VMEM
 NUM_COST_COLS = 2
 
 _LANES = 128
+
+# Largest row-view DMA block, in bytes: its three scratch slots stay
+# well inside the default scoped VMEM.  A wider row takes the flat path.
+_MAX_BLOCK_BYTES = 1 << 20
 
 
 def _is_tpu() -> bool:
@@ -85,9 +104,9 @@ def _n_chunks(n: int, chunk_elems: int) -> tuple[int, int]:
 
 
 def _geometry(n: int, chunk_elems: int, dtype, interpret: bool):
-    """``(n_chunks, rows, lanes)`` of the chunked view of an ``n``-element
-    payload: a single-chunk payload is rounded up to the tile, a
-    multi-chunk one is padded to whole chunks."""
+    """``(n_chunks, rows, lanes)`` of the flat chunked view of an
+    ``n``-element payload: a single-chunk payload is rounded up to the
+    tile, a multi-chunk one is padded to whole chunks."""
     chunk, n_chunks = _n_chunks(n, chunk_elems)
     tile = _tile_elems(dtype)
     if n_chunks == 1:
@@ -101,34 +120,81 @@ def _geometry(n: int, chunk_elems: int, dtype, interpret: bool):
     return n_chunks, chunk // lanes, lanes
 
 
-def _burn(iters: int, seed):
+def _block_rows(width: int, chunk_elems: int, dtype) -> int:
+    """Rows of a row-view DMA block: the smallest whole number of tile
+    rows (sublanes) whose ``width``-wide rows hold ``chunk_elems``."""
+    sub = _tile_elems(dtype) // _LANES
+    return -(-chunk_elems // (width * sub)) * sub
+
+
+def _row_view(shape, dtype, chunk_elems: int) -> tuple[int, int] | None:
+    """``(rows, width)`` view of a payload in its own layout, or ``None``
+    for the flat path: the fewest trailing dims whose product is a
+    multiple of 128 lanes become ``width``, the leading dims ``rows``.
+    A payload with no such split (a 1-D one among them), or whose DMA
+    block would not fit the VMEM scratch, has no row view."""
+    width = 1
+    for k in range(len(shape) - 1, 0, -1):
+        width *= shape[k]
+        if width % _LANES == 0:
+            block = _block_rows(width, chunk_elems, dtype) * width
+            if block * jnp.dtype(dtype).itemsize > _MAX_BLOCK_BYTES:
+                return None
+            return math.prod(shape[:k]), width
+    return None
+
+
+def _burn(iters, seed):
     """The serial dependent fma chain from ``techniques.delay_scalar``,
     executed on the scalar core inside the kernel."""
     return jax.lax.fori_loop(0, iters,
                              lambda j, v: v * 1.0000001 + 1e-9, seed)
 
 
-def _tie_slot(scratch, slot, tok):
-    """Route the slot's first tile through a select on the delay token —
-    the in-kernel mirror of ``techniques.tie``: O(1), bit-identical, and
-    the copy-out cannot be reordered before the burn.  A select, never
-    arithmetic on the payload, so NaN and -0.0 survive."""
-    rows = min(_tile_elems(scratch.dtype) // _LANES, scratch.shape[1])
-    head = scratch[slot, :rows]
-    scratch[slot, :rows] = jnp.where(tok == tok, head, jnp.zeros_like(head))
+def _tie(buf, tok):
+    """Route the buffer's first tile through a select on the delay token
+    — the in-kernel mirror of ``techniques.tie``: O(1), bit-identical,
+    and the copy-out cannot be reordered before the burn.  A select,
+    never arithmetic on the payload, so NaN and -0.0 survive."""
+    rows = min(_tile_elems(buf.dtype) // _LANES, buf.shape[0])
+    lanes = min(_LANES, buf.shape[1])
+    head = buf[:rows, :lanes]
+    buf[:rows, :lanes] = jnp.where(tok == tok, head, jnp.zeros_like(head))
 
 
-def _bounce_kernel(x_hbm, o_hbm, ctr_ref, *, n_chunks: int, copies: int,
-                   iters_per_chunk: int):
+def _bounce_kernel(x_hbm, o_hbm, ctr_ref, *, block_rows: int | None,
+                   n_blocks: int, copies: int, total_iters: int,
+                   total_copies: int):
     """Double-buffered bounce-buffer copy with in-kernel cost accounting.
 
-    ``x_hbm``/``o_hbm`` are ``(n_chunks, rows, lanes)`` HBM views; scratch
-    slots 0/1 double-buffer the chunk DMAs and slot 2 is the extra-pass
-    bounce target.  ``ctr_ref`` is the ``(NUM_COST_COLS,)`` SMEM total."""
+    ``x_hbm``/``o_hbm`` are the flat ``(n_chunks, rows, lanes)`` HBM
+    views (``block_rows`` None: block *i* is leading index *i*) or the
+    ``(rows, width)`` row views (block *i* is rows ``[i·block_rows,
+    (i+1)·block_rows)``, or the whole array where one block holds it).
+    Scratch slots 0/1 double-buffer the block DMAs and slot 2 is the
+    extra-pass bounce target; a whole-array block gets three separate
+    buffers, since Mosaic cannot slice a stacked slot whose rows are a
+    partial tile.  ``total_iters`` burns spread over the blocks, the
+    first ``total_iters % n_blocks`` one iteration longer.  ``ctr_ref``
+    is the ``(NUM_COST_COLS,)`` SMEM total: the iterations burned and
+    ``total_copies``, the passes in accounting chunks."""
+    base, longer = divmod(total_iters, n_blocks)
+    whole = block_rows == x_hbm.shape[0]
+
+    def block(ref, i):
+        if block_rows is None:
+            return ref.at[i]
+        if whole:
+            return ref
+        return ref.at[pl.ds(pl.multiple_of(i * block_rows, block_rows),
+                            block_rows)]
 
     def body(scratch, in_sem, out_sem, pass_sem):
+        def buf(slot):
+            return scratch[slot] if whole else scratch.at[slot]
+
         def dma_in(slot, i):
-            return pltpu.make_async_copy(x_hbm.at[i], scratch.at[slot],
+            return pltpu.make_async_copy(block(x_hbm, i), buf(slot),
                                          in_sem.at[slot])
 
         def extra_passes(slot):
@@ -137,37 +203,44 @@ def _bounce_kernel(x_hbm, o_hbm, ctr_ref, *, n_chunks: int, copies: int,
             # per pass, like the roll/roll-back pair in staged_copy.
             for _ in range(copies - 1):
                 for src, dst in ((slot, 2), (2, slot)):
-                    d = pltpu.make_async_copy(scratch.at[src],
-                                              scratch.at[dst], pass_sem)
+                    d = pltpu.make_async_copy(buf(src), buf(dst), pass_sem)
                     d.start()
                     d.wait()
 
         dma_in(0, 0).start()
 
-        def loop(i, burned):
+        def step(i, burned):
             slot = i % 2
-
-            @pl.when(i + 1 < n_chunks)
-            def _prefetch():
-                dma_in((i + 1) % 2, i + 1).start()
+            if n_blocks > 1:
+                @pl.when(i + 1 < n_blocks)
+                def _prefetch():
+                    dma_in((i + 1) % 2, i + 1).start()
 
             dma_in(slot, i).wait()
             extra_passes(slot)
-            tok = _burn(iters_per_chunk, jnp.float32(1.0))
-            _tie_slot(scratch, slot, tok)
-            out = pltpu.make_async_copy(scratch.at[slot], o_hbm.at[i],
+            iters = base + (i < longer).astype(jnp.int32) if longer else base
+            tok = _burn(iters, jnp.float32(1.0))
+            _tie(buf(slot), tok)
+            out = pltpu.make_async_copy(buf(slot), block(o_hbm, i),
                                         out_sem.at[slot])
             out.start()
             out.wait()
-            return burned + iters_per_chunk * (tok == tok).astype(jnp.int32)
+            return burned + iters * (tok == tok).astype(jnp.int32)
 
-        burned = jax.lax.fori_loop(0, n_chunks, loop, jnp.int32(0))
+        burned = (step(0, jnp.int32(0)) if n_blocks == 1 else
+                  jax.lax.fori_loop(0, n_blocks, step, jnp.int32(0)))
         ctr_ref[COST_ITERS] = burned
-        ctr_ref[COST_COPIES] = jnp.int32(copies * n_chunks)
+        ctr_ref[COST_COPIES] = jnp.int32(total_copies)
 
+    if whole:
+        scratch = (pltpu.VMEM(x_hbm.shape, x_hbm.dtype),) * 3
+    else:
+        slot = x_hbm.shape[1:] if block_rows is None else (
+            (block_rows,) + x_hbm.shape[1:])
+        scratch = pltpu.VMEM((3,) + slot, x_hbm.dtype)
     pl.run_scoped(
         body,
-        scratch=pltpu.VMEM((3,) + x_hbm.shape[1:], x_hbm.dtype),
+        scratch=scratch,
         in_sem=pltpu.SemaphoreType.DMA((2,)),
         out_sem=pltpu.SemaphoreType.DMA((2,)),
         pass_sem=pltpu.SemaphoreType.DMA(()),
@@ -177,38 +250,60 @@ def _bounce_kernel(x_hbm, o_hbm, ctr_ref, *, n_chunks: int, copies: int,
 @functools.partial(
     jax.jit,
     static_argnames=("copies", "delay_iters", "chunk_elems", "interpret"))
-def _bounce_fwd(flat, *, copies: int, delay_iters: int, chunk_elems: int,
+def _bounce_fwd(view, *, copies: int, delay_iters: int, chunk_elems: int,
                 interpret: bool):
-    """Launch the bounce kernel over a flat payload.  Returns
-    ``(out, totals)`` with totals ``(NUM_COST_COLS,)`` int32 from SMEM."""
-    n = flat.shape[0]
-    n_chunks, rows, lanes = _geometry(n, chunk_elems, flat.dtype, interpret)
-    pad = n_chunks * rows * lanes - n
-    view = jnp.pad(flat, (0, pad)) if pad else flat
-    # total delay split evenly across chunks, rounded up: the kernel
-    # burns at least the requested iterations (counters report actuals).
+    """Launch the bounce kernel over a flat (1-D) or row (2-D) view of a
+    payload.  Returns ``(out, totals)``: ``out`` in the view's shape,
+    totals ``(NUM_COST_COLS,)`` int32 from SMEM."""
+    _, n_chunks = _n_chunks(view.size, chunk_elems)
+    # the total delay split evenly across accounting chunks, rounded up:
+    # the kernel burns at least the requested iterations (counters
+    # report actuals), whatever blocks it moves them in.
     iters_per_chunk = -(-delay_iters // n_chunks) if delay_iters > 0 else 0
-    kernel = functools.partial(_bounce_kernel, n_chunks=n_chunks,
-                               copies=copies,
-                               iters_per_chunk=iters_per_chunk)
+    if view.ndim == 1:
+        n = view.shape[0]
+        _, rows, lanes = _geometry(n, chunk_elems, view.dtype, interpret)
+        pad = n_chunks * rows * lanes - n
+        x = (jnp.pad(view, (0, pad)) if pad else view).reshape(
+            n_chunks, rows, lanes)
+        block_rows, n_blocks = None, n_chunks
+    else:
+        x, rows = view, view.shape[0]
+        block_rows = _block_rows(view.shape[1], chunk_elems, view.dtype)
+        if rows <= block_rows:
+            block_rows = rows
+        n_blocks = rows // block_rows
+    kernel = functools.partial(_bounce_kernel, block_rows=block_rows,
+                               n_blocks=n_blocks, copies=copies,
+                               total_iters=iters_per_chunk * n_chunks,
+                               total_copies=copies * n_chunks)
     out, totals = pl.pallas_call(
         kernel,
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=(pl.BlockSpec(memory_space=pl.ANY),
                    pl.BlockSpec(memory_space=pltpu.SMEM)),
-        out_shape=(jax.ShapeDtypeStruct((n_chunks, rows, lanes), flat.dtype),
+        out_shape=(jax.ShapeDtypeStruct(x.shape, x.dtype),
                    jax.ShapeDtypeStruct((NUM_COST_COLS,), jnp.int32)),
         interpret=interpret,
-    )(view.reshape(n_chunks, rows, lanes))
-    out = out.reshape(-1)
-    return (out[:n] if pad else out), totals
+    )(x)
+    if view.ndim == 1:
+        out = out.reshape(-1)
+        return (out[:n] if pad else out), totals
+    # the rows past the last whole block: a tiled dim cannot be sliced
+    # by a DMA at a partial tile, so they are written into the kernel's
+    # output in place, after it
+    done = n_blocks * block_rows
+    if done < rows:
+        out = jax.lax.dynamic_update_slice(out, view[done:], (done, 0))
+    return out, totals
 
 
 def _launch(x, *, copies: int, delay_iters: int, chunk_elems: int,
             interpret: bool | None):
     if interpret is None:
         interpret = not _is_tpu()
-    out, totals = _bounce_fwd(x.reshape(-1), copies=int(copies),
+    view = _row_view(x.shape, x.dtype, chunk_elems)
+    out, totals = _bounce_fwd(x.reshape(view or (-1,)), copies=int(copies),
                               delay_iters=int(delay_iters),
                               chunk_elems=int(chunk_elems),
                               interpret=bool(interpret))

@@ -24,11 +24,13 @@ from repro.kernels.dataplane import (
     COST_ITERS,
     bounce_copy,
     kernel_calibrate,
+    kernel_cost_totals,
     kernel_iters_for_ns,
     mediated_cost,
     rescale_iters,
     use_pallas_dataplane,
 )
+from repro.kernels.dataplane.bounce import _row_view
 
 
 # ---------------------------------------------------------------------------
@@ -43,7 +45,54 @@ BOUNCE_CASES = [
     ((3, 5, 7), jnp.bfloat16, 1, 32),     # nd payload, odd extents
     ((1,), jnp.float32, 2, 8192),         # single element
     ((4096,), jnp.int32, 1, 1024),        # exact multiple: no tail path
+    # row views: the payload's own (rows, width) layout, tile-aligned
+    # blocks of rows, the ragged rows written in after the kernel
+    ((51, 256), jnp.bfloat16, 1, 8192),   # 3 rows past the last block
+    ((3, 8192), jnp.bfloat16, 2, 8192),   # one whole-array block
+    ((100, 512), jnp.bfloat16, 3, 1024),  # 6 blocks of 16 + 4 rows
+    ((3, 1, 32, 64), jnp.bfloat16, 1, 8192),  # trailing dims merged
+    ((37, 384), jnp.float32, 2, 1024),    # ragged rows, f32 tiles
 ]
+
+# payloads for the value and counter checks of both entry points:
+# (shape, dtype, chunk_elems); the flat path, then row views
+PAYLOADS = [
+    ((5,), jnp.float32, 2),
+    ((128,), jnp.float32, 32),
+    ((51, 256), jnp.bfloat16, 8192),
+    ((3, 8192), jnp.bfloat16, 8192),
+    ((100, 512), jnp.bfloat16, 1024),
+    ((3, 1, 32, 64), jnp.bfloat16, 8192),
+    ((37, 384), jnp.float32, 1024),
+]
+
+
+def _nonfinite(shape, dtype):
+    """A seeded payload with NaN, -0.0 and both infinities in it."""
+    x = np.array(jax.random.normal(jax.random.PRNGKey(1), shape),
+                 np.float32).reshape(-1)
+    x[: 4] = [np.nan, -0.0, np.inf, -np.inf]
+    x[-1] = -0.0
+    return jnp.asarray(x.reshape(shape)).astype(dtype)
+
+
+def _bits(a):
+    a = np.asarray(a)
+    return a.view({2: np.uint16, 4: np.uint32}[a.dtype.itemsize])
+
+
+def test_row_view_choice():
+    # the view comes from the shape alone: the fewest trailing dims that
+    # make a lane-aligned width, with leading dims left over
+    assert _row_view((49155, 2048), jnp.bfloat16, 8192) == (49155, 2048)
+    assert _row_view((484, 16, 512), jnp.bfloat16, 8192) == (7744, 512)
+    assert _row_view((3, 1, 8192), jnp.bfloat16, 8192) == (3, 8192)
+    assert _row_view((3, 1, 32, 64), jnp.bfloat16, 8192) == (3, 2048)
+    # flat path: 1-D, no lane-aligned split, a block too wide for VMEM
+    assert _row_view((4096,), jnp.float32, 8192) is None
+    assert _row_view((3, 1, 49155), jnp.float32, 8192) is None
+    assert _row_view((3, 5, 7), jnp.bfloat16, 32) is None
+    assert _row_view((2, 1 << 20), jnp.float32, 8192) is None
 
 
 @pytest.mark.parametrize("shape,dtype,copies,chunk", BOUNCE_CASES)
@@ -60,34 +109,42 @@ def test_bounce_copy_zero_copies_is_identity():
     assert bounce_copy(x, copies=0) is x
 
 
-def test_bounce_copy_nonfinite_payload_bit_identical():
+@pytest.mark.parametrize("shape,dtype,chunk", PAYLOADS)
+def test_bounce_copy_nonfinite_payload_bit_identical(shape, dtype, chunk):
     # the in-kernel tie must survive NaN / -0.0 (a select, not arithmetic)
-    x = jnp.array([jnp.nan, -0.0, jnp.inf, -jnp.inf, 1.5], jnp.float32)
-    got = np.asarray(bounce_copy(x, copies=2, chunk_elems=2))
-    np.testing.assert_array_equal(
-        got.view(np.int32), np.asarray(x).view(np.int32))
+    x = _nonfinite(shape, dtype)
+    got = bounce_copy(x, copies=2, chunk_elems=chunk)
+    assert got.shape == x.shape and got.dtype == x.dtype
+    np.testing.assert_array_equal(_bits(got), _bits(x))
 
 
 # ---------------------------------------------------------------------------
 # mediated_cost: delay_chain tie semantics + per-chunk counters
 # ---------------------------------------------------------------------------
 
-def test_mediated_cost_value_identical():
-    x = jnp.array([jnp.nan, -0.0, 2.0, -1.0], jnp.float32)
-    out, _ = mediated_cost(x, delay_iters=100, copies=1, chunk_elems=2)
-    np.testing.assert_array_equal(
-        np.asarray(out).view(np.int32), np.asarray(x).view(np.int32))
+@pytest.mark.parametrize("shape,dtype,chunk", PAYLOADS)
+def test_mediated_cost_value_identical(shape, dtype, chunk):
+    x = _nonfinite(shape, dtype)
+    out, _ = mediated_cost(x, delay_iters=100, copies=1, chunk_elems=chunk)
+    assert out.shape == x.shape and out.dtype == x.dtype
+    np.testing.assert_array_equal(_bits(out), _bits(x))
 
 
-def test_mediated_cost_counters():
-    x = jnp.zeros((128,), jnp.float32)
-    out, ctrs = mediated_cost(x, delay_iters=50, copies=2, chunk_elems=32)
+@pytest.mark.parametrize("shape,dtype,chunk", PAYLOADS)
+def test_mediated_cost_counters(shape, dtype, chunk):
+    # the cost is accounted in chunk_elems-element chunks whatever view
+    # the kernel moves the payload in: the counters are the host mirror's
+    x = jnp.zeros(shape, dtype)
+    out, ctrs = mediated_cost(x, delay_iters=50, copies=2, chunk_elems=chunk)
     ctrs = np.asarray(ctrs)
-    assert ctrs.shape == (4, 2)
-    # even split rounded up: every chunk burns ceil(50/4) = 13
-    np.testing.assert_array_equal(ctrs[:, COST_ITERS], 13)
+    n_chunks = -(-x.size // chunk)
+    assert ctrs.shape == (n_chunks, 2)
+    # even split rounded up: every chunk burns ceil(50 / n_chunks)
+    np.testing.assert_array_equal(ctrs[:, COST_ITERS], -(-50 // n_chunks))
     np.testing.assert_array_equal(ctrs[:, COST_COPIES], 2)
     assert ctrs[:, COST_ITERS].sum() >= 50
+    assert (ctrs[:, COST_ITERS].sum(), ctrs[:, COST_COPIES].sum()) == \
+        kernel_cost_totals(x.size, 50, 2, chunk)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(x))
 
 
@@ -136,8 +193,9 @@ def test_kernel_calibration_off_tpu_matches_xla_slope():
 # pipeline-level equivalence: pallas on ≡ off, bit for bit
 # ---------------------------------------------------------------------------
 
+@pytest.mark.parametrize("shape", [(64,), (64, 128)])
 @pytest.mark.parametrize("fused", [True, False])
-def test_pipeline_pallas_bit_identical(mesh8, fused):
+def test_pipeline_pallas_bit_identical(mesh8, fused, shape):
     outs, reports = {}, {}
     for pallas in ("off", "on"):
         dp = Dataplane(
@@ -154,7 +212,7 @@ def test_pipeline_pallas_bit_identical(mesh8, fused):
             return r, rt
 
         out, rt = jax.jit(f)(
-            jax.random.normal(jax.random.PRNGKey(3), (64,)),
+            jax.random.normal(jax.random.PRNGKey(3), shape),
             dp.runtime_init())
         outs[pallas] = np.asarray(out)
         reports[pallas] = dp.runtime_report(rt)["default"]
