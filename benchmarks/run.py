@@ -10,7 +10,6 @@ Sections:
   fig3/fig4 — CoRD overhead matrix & relative throughput (Figs. 3-4)
   window    — CQ-runtime bandwidth vs. sender-window depth (RC + UD)
   credits   — credit flow-control ablation (stall counters)
-  serve     — gang vs continuous-slot serving (tok/s, TTFT, compiles)
   converged — train job + serve tenants on ONE dataplane under QoS
               arbitration (the converged-cloud scenario)
   fig5      — system-A preset (Fig. 5)
@@ -544,10 +543,6 @@ def main() -> None:
     from benchmarks import npb
     rows += npb.run_all()
 
-    print("# serve (gang vs continuous slots)")
-    from benchmarks import serve
-    rows += serve.run_all(fast=fast)
-
     print("# converged (train + serve on one dataplane)")
     from benchmarks import converged
     rows += converged.run_all(fast=fast)
@@ -592,10 +587,6 @@ def main() -> None:
                   f"drop{r['drop_rate']},,retrans={r['retransmits']} "
                   f"timeouts={r['timeouts']} "
                   f"bit_identical={r['bit_identical']}")
-        elif tab == "serve":
-            print(f"serve/{r['scheduler']}/q{r['queue_depth']},,"
-                  f"tok_s={r['tok_s']} ttft_ms={r['ttft_ms_mean']} "
-                  f"compiles={r['decode_compiles']}")
         elif tab == "converged":
             served = sum(r["served_tokens"].values())
             print(f"converged/throttle={r['throttle_train']},,"

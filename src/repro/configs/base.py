@@ -305,11 +305,6 @@ class ServeConfig:
     max_new_tokens: int = 32
     temperature: float = 0.0
     kv_cache_len: int = 4096
-    # Slot scheduler: "continuous" = persistent decode slots with
-    # mid-decode WFQ refill (fixed-shape decode step, compiled once);
-    # "gang" = legacy batch-to-completion scheduling (convoy effect,
-    # shape-derived recompiles) — kept as the benchmark baseline.
-    scheduler: str = "continuous"
     # Host-bucket admission charges len(prompt) tokens per request; rate
     # and burst from QoSPolicy.rates (defined in ops) scale by this many
     # tokens per traced-rate unit.

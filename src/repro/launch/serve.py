@@ -4,8 +4,7 @@ dataplane.
 ``python -m repro.launch.serve [--arch granite-3-2b] [--full]
 [--param-dtype bfloat16] [--mode cord|bypass|socket] [--requests 8]
 [--prompt-lens 6,7,8,9,10] [--kv-len 128] [--tenants default]
-[--scheduler continuous|gang] [--block-size 16] [--n-blocks N]
-[--prefill-chunk 512] [--timeline]``
+[--block-size 16] [--n-blocks N] [--prefill-chunk 512] [--timeline]``
 
 The engine runs on a :class:`~repro.core.dataplane.Dataplane` of the
 chosen mode with emulated OS costs on, over a one-device mesh, so every
@@ -96,8 +95,6 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--kv-len", type=int, default=128,
                     help="per-request cache bound in tokens (prefill cover "
                          "+ new tokens + 1 must fit)")
-    ap.add_argument("--scheduler", default="continuous",
-                    choices=("continuous", "gang"))
     ap.add_argument("--max-batch", type=int, default=4)
     ap.add_argument("--block-size", type=int, default=0,
                     help="paged KV: pool block size in tokens (0 = legacy "
@@ -150,7 +147,6 @@ def build_engine(cfg, model, params, args, devices, *, obs=None,
                   ServeConfig(max_batch=args.max_batch,
                               max_new_tokens=args.max_new_tokens,
                               kv_cache_len=kv_len,
-                              scheduler=args.scheduler,
                               block_size=args.block_size,
                               n_blocks=args.n_blocks,
                               prefill_chunk=args.prefill_chunk),
@@ -200,7 +196,7 @@ def main(argv=None) -> None:
     toks = sum(len(r.out_tokens) for r in done)
     ttft = [r.t_first - t0 for r in done if r.t_first is not None]
     print(f"served {len(done)} requests, {toks} tokens "
-          f"in {dt:.2f}s ({toks/dt:.1f} tok/s, {args.scheduler} scheduler, "
+          f"in {dt:.2f}s ({toks/dt:.1f} tok/s, "
           f"{args.mode} dataplane on {jax.devices()[0].platform}, "
           f"{eng.decode_compile_count()} decode compiles, "
           f"mean TTFT {1e3*sum(ttft)/max(len(ttft),1):.0f} ms)")

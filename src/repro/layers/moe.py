@@ -9,9 +9,10 @@ keeps arctic-480b (128 experts) inside HBM at 256-way SPMD.
 
 Capacity dropping applies at **training only**.  Inference (``train=False``)
 is dropless (C = n·k over a small block), because serving exactness —
-continuous batching ≡ gang decode at temperature 0, bit-exact slot
-preempt/resume — requires per-token outputs that are invariant to batch
-composition, and capacity races between co-resident tokens break that.
+continuous batching ≡ the batch-1 greedy reference decode at temperature
+0 (tests/conftest.py), bit-exact slot preempt/resume — requires
+per-token outputs that are invariant to batch composition, and capacity
+races between co-resident tokens break that.
 
 Sharding (via the dataplane): blocks G → data axis, experts E → model
 axis.  The G↔E resharding between dispatch and expert compute is the EP
@@ -113,10 +114,10 @@ def moe(params: dict, x: jax.Array, cfg: MoEConfig, *, act: str = "silu",
     """Apply the MoE layer. x: (B, S, D). Returns (out, aux_loss)."""
     b, s, d = x.shape
     tokens = b * s
-    # Inference is dropless: serving correctness (continuous ≡ gang at
-    # temp 0, slot-exact preempt/resume) needs per-token outputs that do
-    # not depend on which other rows share the batch, and capacity
-    # dropping is exactly such a coupling (the block cumsum races tokens
+    # Inference is dropless: serving correctness (continuous ≡ batch-1
+    # reference decode at temp 0, slot-exact preempt/resume) needs
+    # per-token outputs that do not depend on which other rows share the
+    # batch, and capacity dropping is exactly such a coupling (the block cumsum races tokens
     # for expert queue slots).  With C = n·k no token can ever drop, and
     # co-token contributions enter every einsum as exact zeros, so each
     # token's output is invariant to grouping and batch composition.

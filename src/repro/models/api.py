@@ -32,10 +32,10 @@ class Model:
     init_cache: Callable
     prefill: Callable
     decode_step: Callable
-    # Fixed-shape decode over persistent slots (per-slot positions).  None
-    # for families without a slot-aware decode path; the serving engine
-    # falls back to gang scheduling when absent.
-    decode_step_slots: Callable | None = None
+    # Fixed-shape decode over persistent slots (per-slot positions): the
+    # serving engine's decode step on the stripe layout, which every
+    # family's cache supports.
+    decode_step_slots: Callable
     # Fixed-shape decode over slots whose caches live in a shared block
     # pool, read in place through per-slot block tables.  None for
     # families whose cache cannot be paged; the engine then refuses

@@ -227,9 +227,10 @@ def encdec_prefill(params, cfg, batch, cache, *, dp=None, impl="flash",
     The serve engine submits token-only batches; the conv/mel frontend is
     a stub, so when ``frames`` is absent a zero frame window of the
     configured encoder geometry is synthesized — deterministic, identical
-    across gang and continuous paths.  ``last_pos`` (B,) picks the hidden
-    position whose logits are returned (right padding after the prompt is
-    causally inert for the decoder, so bucketed prefill stays exact)."""
+    for the engine and the batch-1 reference decode (tests/conftest.py).
+    ``last_pos`` (B,) picks the hidden position whose logits are returned
+    (right padding after the prompt is causally inert for the decoder, so
+    bucketed prefill stays exact)."""
     if "frames" not in batch:
         b = batch["tokens"].shape[0]
         batch = dict(batch, frames=jnp.zeros(
@@ -274,7 +275,8 @@ def encdec_decode_step(params, cfg, token, cache, pos, *, dp=None, **_):
 def encdec_decode_step_slots(params, cfg, token, cache, pos, *, dp=None, **_):
     """Fixed-shape slot decode: every slot advances one token at its own
     position ``pos`` (B,).  Sinusoidal positions are gathered per slot
-    (``tbl[pos]``) instead of the gang path's scalar slice; self-attention
+    (``tbl[pos]``) instead of ``encdec_decode_step``'s scalar slice, which
+    the batch-1 reference decode runs (tests/conftest.py); self-attention
     masks per slot; cross-attention reads each slot's full encoder
     snapshot (cross_k/cross_v rows inserted by ``state_slot_insert``)."""
     dtype = dtype_of(cfg.dtype)

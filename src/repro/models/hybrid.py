@@ -72,7 +72,9 @@ def _block(lp, x, *, cfg, dp, positions, window, theta, mode,
         # per-slot write positions ``cache_pos`` (B,).  Same batched-mask
         # naive attend as the transformer's decode_slots — exact and tiny
         # at q=1.  The mamba branch below is already per-row recurrent, so
-        # only the attention mask changes between gang and slot decode.
+        # only the attention mask changes between the scalar-position
+        # decode (the batch-1 reference loop, tests/conftest.py) and slot
+        # decode.
         ck, cv = kv_update_slots(cache["k"], cache["v"], k, v, cache_pos)
         s_max = ck.shape[1]
         k_pos = jnp.arange(s_max, dtype=jnp.int32)

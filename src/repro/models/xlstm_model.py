@@ -175,8 +175,9 @@ def xlstm_decode_step_slots(params, cfg, token, cache, pos, *, dp=None, **_):
     Decode here is position-free — the recurrence carries all sequence
     context in the (reps, B, ...) unit states, and every batch row
     advances independently — so the per-slot ``pos`` vector the engine
-    feeds is simply unused and the gang decode step IS the slot decode
-    step.  A freed slot's state keeps evolving on stale tokens until
+    feeds is simply unused and ``xlstm_decode_step`` (the step the batch-1
+    reference decode runs, tests/conftest.py) IS the slot decode step.  A
+    freed slot's state keeps evolving on stale tokens until
     ``state_slot_insert`` overwrites the whole row at the next insert."""
     del pos
     return xlstm_decode_step(params, cfg, token, cache, 0, dp=dp)

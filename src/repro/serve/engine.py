@@ -1,8 +1,7 @@
 """Serving engine: persistent-slot continuous batching with a fixed-shape
 decode step, WFQ slot packing, and per-tenant admission control.
 
-**Slot lifecycle** (``scheduler="continuous"``, the default whenever the
-model family has a slot-aware decode path):
+**Slot lifecycle**:
 
 1. The engine preallocates ONE ``(layers, max_batch, kv_cache_len, ...)``
    KV cache whose batch rows are long-lived *slots*, plus per-slot
@@ -16,11 +15,14 @@ model family has a slot-aware decode path):
 4. One jitted decode step advances ALL slots each tick.  Its shapes are
    functions of the slot geometry only — ``(max_batch, 1)`` tokens,
    ``(max_batch,)`` positions, the fixed cache — so it compiles **once
-   per engine** regardless of the request mix (vs. one compile per
-   distinct batch shape under gang scheduling).
+   per engine** regardless of the request mix.
 5. A slot that finishes (EOS or token budget) is refilled from the queue
    *mid-decode* — no convoy effect: co-residents keep decoding while the
    freed slot takes new work.
+
+At temperature 0 a request's tokens do not depend on its prompt bucket
+or its co-residents: right-padded buckets sit causally after the prompt,
+and a slot's stale bytes are validity-masked.
 
 **WFQ slot packing** is the QoS mechanism: a weighted-fair-queueing
 scheduler (:class:`WFQScheduler`) keeps a virtual time per tenant, with
@@ -77,19 +79,6 @@ and every program is a named function (``serve_decode``,
 ``jax.profiler`` trace of ``Engine.run`` ties each device module to its
 phase on one clock.  Outside a profiler session a span costs about a
 microsecond.
-
-``scheduler="gang"`` keeps the legacy behaviour — admit up to
-``max_batch`` requests, batch-prefill them left-padded, decode the gang
-to completion with shape-derived (recompiling) prefill/decode steps —
-as the benchmark baseline and the fallback for model families without
-``decode_step_slots``.
-
-At temperature 0 both schedulers produce identical output tokens when
-gang batches carry uniform prompt lengths.  With mixed lengths the gang
-path left-pads to the batch max and *attends the pads* (a legacy gang
-property), perturbing its logits; the continuous path is padding-
-invariant by construction (right-padded buckets sit causally after the
-prompt; stale slot bytes are validity-masked).
 """
 
 from __future__ import annotations
@@ -227,42 +216,31 @@ class Engine:
         self.on_tick = None
         # cache sharding edges are issued inside the traced prefill, so
         # policy enforcement/telemetry happen once per compiled shape (like
-        # every other dataplane edge), not once per host batching round
-        # every program is a named function: XLA names its module
+        # every other dataplane edge), not once per host batching round.
+        # Every program is a named function: XLA names its module
         # ``jit_<name>`` on the device and ``PjitFunction(<name>)`` on the
         # host, which is how a profiler trace ties device time to the
-        # engine's phases (docs/observability.md "Spans")
-        def gang_prefill(p, b, c):
-            return model.prefill(p, b, kv_cache_constrain(dp, c), dp=dp)
+        # engine's phases (docs/observability.md "Spans").
+        # Prefill-to-slot is ONE traced op: batch-1 bucketed prefill whose
+        # cache lands directly in the target slot of the persistent cache
+        # (one dispatch per admitted request, one compile per prompt
+        # bucket).
+        def serve_prefill(p, t, pc, cache, slot, last):
+            logits, pc = model.prefill(p, {"tokens": t},
+                                       kv_cache_constrain(dp, pc),
+                                       dp=dp, last_pos=last)
+            # family-agnostic: writes KV stripe leaves AND recurrent /
+            # cross-attention state leaves at their batch row
+            return logits, state_slot_insert(cache, pc, slot)
 
-        def gang_decode(p, t, c, pos):
-            return model.decode_step(p, t, c, pos, dp=dp)
+        def serve_decode(p, t, c, pos):
+            return model.decode_step_slots(p, t, c, pos, dp=dp)
 
-        self._prefill = jax.jit(gang_prefill)
-        self._step = jax.jit(gang_decode)
-        step_slots = getattr(model, "decode_step_slots", None)
-        self._slot_support = step_slots is not None
-        if self._slot_support:
-            # prefill-to-slot is ONE traced op: batch-1 bucketed prefill
-            # whose cache lands directly in the target slot of the
-            # persistent cache (one dispatch per admitted request, one
-            # compile per prompt bucket)
-            def serve_prefill(p, t, pc, cache, slot, last):
-                logits, pc = model.prefill(p, {"tokens": t},
-                                           kv_cache_constrain(dp, pc),
-                                           dp=dp, last_pos=last)
-                # family-agnostic: writes KV stripe leaves AND recurrent /
-                # cross-attention state leaves at their batch row
-                return logits, state_slot_insert(cache, pc, slot)
-
-            def serve_decode(p, t, c, pos):
-                return step_slots(p, t, c, pos, dp=dp)
-
-            # the persistent cache is donated: XLA updates it in place
-            # instead of copying the full buffer per tick / per insert
-            # (a no-op with a warning on backends without aliasing)
-            self._prefill_slot = jax.jit(serve_prefill, donate_argnums=(3,))
-            self._step_slots = jax.jit(serve_decode, donate_argnums=(2,))
+        # the persistent cache is donated: XLA updates it in place instead
+        # of copying the full buffer per tick / per insert (a no-op with a
+        # warning on backends without aliasing)
+        self._prefill_slot = jax.jit(serve_prefill, donate_argnums=(3,))
+        self._step_slots = jax.jit(serve_decode, donate_argnums=(2,))
 
         # True for the recurrent families (mamba/xLSTM state): the engine
         # prefills them at exact prompt length — right padding advances a
@@ -276,8 +254,7 @@ class Engine:
         self.paged = bs > 0
         step_paged = getattr(model, "decode_step_paged", None)
         if self.paged:
-            spec = (jax.eval_shape(lambda: model.init_cache(1, bs))
-                    if self._slot_support else None)
+            spec = jax.eval_shape(lambda: model.init_cache(1, bs))
             pageable = (step_paged is not None and isinstance(spec, dict)
                         and set(spec) == {"k", "v"}
                         and all(len(v.shape) == 5 for v in spec.values()))
@@ -292,7 +269,6 @@ class Engine:
                     f"(--block-size 0) to serve this family on the fixed "
                     f"stripe layout (continuous batching, chunk-exact "
                     f"preemption and WFQ budgets all still apply).")
-        if self.paged:
             ks = spec["k"]
             # (layers, kv_heads, head_dim, dtype) from the model's own
             # cache layout, so the pool matches it bit-for-bit
@@ -328,8 +304,7 @@ class Engine:
 
         # ---- chunked prefill (prefill_chunk > 0) ----------------------
         chunk_fn = getattr(model, "prefill_chunk", None)
-        self.chunked = (serve.prefill_chunk > 0 and chunk_fn is not None
-                        and self._slot_support)
+        self.chunked = serve.prefill_chunk > 0 and chunk_fn is not None
         if self.chunked:
             def serve_prefill_chunk(p, t, c, off, last):
                 return chunk_fn(p, {"tokens": t}, kv_cache_constrain(dp, c),
@@ -385,39 +360,6 @@ class Engine:
         cost = float(len(r.prompt))
         return min(cost, bucket.burst) if bucket is not None else cost
 
-    def _admit_batch(self, queue: list[Request]) -> tuple[list[Request],
-                                                          list[Request]]:
-        """Gang admission: pick up to ``max_batch`` requests the buckets
-        admit; the rest stay queued.  Refills until at least one request
-        is admissible (guaranteed progress).  Bucket starvation is
-        observed with ``can_take`` *before* the batch-fullness check, so
-        a starved request behind a full batch is still counted as
-        deferred (once per batching round, on the round's first refill);
-        the bucket is only debited — by ``len(prompt)`` tokens — when the
-        request is actually admitted."""
-        B = self.scfg.max_batch
-        for round_ in range(_MAX_STARVED_ROUNDS):
-            for b in self._buckets.values():
-                b.refill()
-            admitted, deferred = [], []
-            for r in queue:
-                bucket = self._buckets.get(r.tenant)
-                cost = self._admission_cost(r, bucket)
-                if bucket is not None and not bucket.can_take(cost):
-                    if round_ == 0:
-                        self.tenant_stats[r.tenant]["deferrals"] += 1
-                    deferred.append(r)
-                elif len(admitted) < B:
-                    if bucket is not None:
-                        bucket.take(cost)
-                    admitted.append(r)
-                else:
-                    deferred.append(r)
-            if admitted:
-                return admitted, deferred
-        # pathological rates (≈0): force progress with the queue head
-        return queue[:1], queue[1:]
-
     def _obs_snapshot(self, *, active: int, queued: int) -> None:
         """The end of one engine tick, seen from outside: the attached
         timeline gets the snapshot that is due (the serve counter block —
@@ -437,14 +379,6 @@ class Engine:
             self.on_tick(self)
 
     # ------------------------------------------------------------------
-    def _pad_prompts(self, reqs: list[Request]) -> np.ndarray:
-        cap = max(len(r.prompt) for r in reqs)
-        cap = max(cap, 8)
-        toks = np.zeros((len(reqs), cap), np.int32)
-        for i, r in enumerate(reqs):
-            toks[i, -len(r.prompt):] = r.prompt      # left-pad
-        return toks
-
     def _finish(self, r: Request, done: list[Request]) -> None:
         r.done = True
         stats = self.tenant_stats[r.tenant]
@@ -464,29 +398,10 @@ class Engine:
     # ------------------------------------------------------------------
     # public entry
     # ------------------------------------------------------------------
-    def run(self, requests: list[Request], rng=None,
-            scheduler: str | None = None) -> list[Request]:
-        """Serve all requests to completion; returns them with outputs.
-
-        ``scheduler`` overrides ``ServeConfig.scheduler`` for this run;
-        "continuous" silently falls back to "gang" when the model family
-        has no slot-aware decode path."""
+    def run(self, requests: list[Request], rng=None) -> list[Request]:
+        """Serve all requests to completion; returns them with outputs."""
         rng = rng if rng is not None else jax.random.PRNGKey(0)
-        sched = scheduler or self.scfg.scheduler
-        if sched not in ("continuous", "gang"):
-            raise ValueError(f"unknown scheduler {sched!r}; "
-                             f"expected 'continuous' or 'gang'")
-        if sched == "continuous" and self._slot_support:
-            return self._run_continuous(list(requests), rng)
-        for r in requests:               # clear error, never a mid-decode
-            need = len(r.prompt) + \
-                min(r.max_new_tokens, self.scfg.max_new_tokens) + 1
-            if need > self.scfg.kv_cache_len:
-                raise ServeError(
-                    f"gang request needs {need} cache positions (prompt "
-                    f"{len(r.prompt)} + new tokens + 1) but kv_cache_len "
-                    f"is {self.scfg.kv_cache_len}")
-        return self._run_gang(list(requests), rng)
+        return self._run_continuous(list(requests), rng)
 
     # ------------------------------------------------------------------
     # continuous: persistent slots, fixed-shape decode, WFQ packing
@@ -973,54 +888,6 @@ class Engine:
         return cache, rng
 
     # ------------------------------------------------------------------
-    # gang (legacy baseline): batch to completion, shape-derived compiles
-    # ------------------------------------------------------------------
-    def _run_gang(self, requests: list[Request], rng) -> list[Request]:
-        queue = list(requests)
-        done: list[Request] = []
-
-        while queue:
-            batch_reqs, queue = self._admit_batch(queue)
-            toks = self._pad_prompts(batch_reqs)
-            b, prompt_len = toks.shape
-            cache_len = prompt_len + self.scfg.max_new_tokens + 1
-            cache = self.model.init_cache(b, cache_len)
-            logits, cache = self._prefill(self.params,
-                                          {"tokens": jnp.asarray(toks)}, cache)
-            rng, k = jax.random.split(rng)
-            tok = sample(logits[:, -1, :], k, self.scfg.temperature)[:, None]
-            limits = [min(r.max_new_tokens, self.scfg.max_new_tokens)
-                      for r in batch_reqs]
-            active = np.ones(b, bool)
-            for j, (r, t) in enumerate(zip(batch_reqs, np.asarray(tok)[:, 0])):
-                self._emit(r, int(t), logits, j)
-                if t == self.eos_id or limits[j] <= 1:
-                    active[j] = False
-
-            for i in range(self.scfg.max_new_tokens - 1):
-                if not active.any():
-                    break
-                pos = jnp.asarray(prompt_len + i, jnp.int32)
-                logits, cache = self._step(self.params, tok, cache, pos)
-                rng, k = jax.random.split(rng)
-                tok = sample(logits[:, -1, :], k, self.scfg.temperature)[:, None]
-                arr = np.asarray(tok)[:, 0]
-                for j, r in enumerate(batch_reqs):
-                    if active[j]:
-                        self._emit(r, int(arr[j]), logits, j)
-                        # a slot whose request hits EOS or its token budget
-                        # goes IDLE for the rest of the gang — the convoy
-                        # effect continuous slot refill removes
-                        if arr[j] == self.eos_id or \
-                                len(r.out_tokens) >= limits[j]:
-                            active[j] = False
-                self._obs_snapshot(active=int(active.sum()),
-                                   queued=len(queue))
-            for r in batch_reqs:
-                self._finish(r, done)
-        return done
-
-    # ------------------------------------------------------------------
     # reporting
     # ------------------------------------------------------------------
     def tenant_report(self) -> dict[str, dict[str, float]]:
@@ -1061,11 +928,11 @@ class Engine:
 
     def decode_compile_count(self) -> int:
         """Decode-step compilations so far (jit cache entries across the
-        gang and slot decode steps) — continuous batching holds this at 1
-        per engine; gang scheduling pays one per distinct batch shape."""
+        stripe and pool decode steps) — one per engine, whatever the
+        request mix."""
         return sum(f._cache_size()
-                   for f in (getattr(self, "_step_slots", None),
-                             getattr(self, "_step_pool", None), self._step)
+                   for f in (self._step_slots,
+                             getattr(self, "_step_pool", None))
                    if f is not None)
 
 

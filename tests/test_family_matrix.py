@@ -2,13 +2,13 @@
 
 Every config preset — including the downscaled big-model shims
 (``arctic-480b``, ``llava-next-34b``) — is driven through the full
-serving stack: continuous batching on persistent slots, gang decode,
-timeline snapshots, the elastic slot-budget trigger, and preemption with
-resume.  The matrix asserts the properties the converged-dataplane story
-depends on:
+serving stack: continuous batching on persistent slots, timeline
+snapshots, the elastic slot-budget trigger, and preemption with resume.
+The matrix asserts the properties the converged-dataplane story depends
+on:
 
-* continuous ≡ gang at temperature 0 (per family, uniform prompts so the
-  gang path adds no left padding),
+* continuous ≡ the batch-1 greedy reference at temperature 0 (per
+  family: each request's plain prefill-then-decode loop, served alone),
 * preempt → resume is EXACT at temperature 0 (the emitted tokens are the
   snapshot; recompute-based resume must replay them bit-identically,
   including through the mamba/xLSTM recurrences),
@@ -72,21 +72,15 @@ def _serve_cfg(**kw):
 
 
 @pytest.mark.parametrize("arch", ARCHS)
-def test_continuous_matches_gang_temp0(arch):
-    """Greedy continuous batching ≡ gang decode, every family.
-
-    Uniform prompt lengths ≥ 8: the gang path left-pads to the batch max
-    (and attends the pads), so unequal lengths would compare different
-    *models of the prompt*, not different schedulers."""
+def test_continuous_matches_reference_temp0(arch, greedy_reference):
+    """Greedy continuous batching ≡ the batch-1 reference loop (prefill,
+    then scalar-position decode, one request alone), every family."""
     cfg, model, params = family_model(arch)
     cont = Engine(model, params, cfg, _serve_cfg(), eos_id=-1)
-    gang = Engine(model, params, cfg, _serve_cfg(), eos_id=-1)
     reqs = _requests([8] * 5)
-    out_c = _outs(cont.run([Request(rid=r.rid, prompt=r.prompt.copy(),
-                                    max_new_tokens=r.max_new_tokens)
-                            for r in reqs]))
-    out_g = _outs(gang.run(reqs, scheduler="gang"))
-    assert out_c == out_g
+    out_c = _outs(cont.run(reqs))
+    want = greedy_reference(model, params, [r.prompt for r in reqs], 5)
+    assert out_c == dict(enumerate(want))
     assert all(len(v) == 5 for v in out_c.values())
     # ONE decode compile regardless of family: the fixed-shape slot step
     assert cont.decode_compile_count() == 1
@@ -184,7 +178,7 @@ def test_elastic_trigger_drives_slot_budget(arch):
 @pytest.mark.parametrize("arch", ARCHS)
 def test_paged_support_matches_cache_layout(arch):
     """block_size > 0 either pages (pure rank-5 {k,v} stripe) or raises
-    the family-naming ServeError — never a silent gang fallback."""
+    the family-naming ServeError — never a silent fallback."""
     cfg, model, params = family_model(arch)
     sc = _serve_cfg(kv_cache_len=64, block_size=8, n_blocks=24)
     if cfg.family in _PAGEABLE:

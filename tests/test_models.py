@@ -84,8 +84,8 @@ def test_moe_decode_finite_and_batch_dependent():
     """MoE decode produces finite logits.  Eval-mode routing is dropless
     (layers/moe.py), so per-token outputs are batch-invariant — the serve
     conformance matrix (tests/test_family_matrix.py) asserts the exact
-    continuous ≡ gang equality; here we keep the cheap shape/finiteness
-    smoke on the raw prefill/decode hooks."""
+    continuous ≡ batch-1 reference equality; here we keep the cheap
+    shape/finiteness smoke on the raw prefill/decode hooks."""
     cfg = get_model_config("arctic-480b", smoke=True)
     cfg = dataclasses.replace(cfg, dtype="float32")
     model = build_model(cfg)
@@ -139,13 +139,14 @@ def _trinity_requests(lengths, max_new=5):
                     max_new_tokens=max_new) for i, n in enumerate(lengths)]
 
 
-def test_trinity_serves_on_the_normal_path():
+def test_trinity_serves_on_the_normal_path(greedy_reference):
     """trinity-mini's smoke preset (a dense layer, then MoE layers at an
     expert share of 2 held of 4, sliding and RoPE-less full attention)
     through build_model and the paged, chunk-prefilling engine: every
     request gets its tokens, identical to whole prefill on the stripe
-    layout, and continuous batching equals gang decode at temperature 0
-    (the expert layer's outputs do not depend on batch composition)."""
+    layout, and continuous batching equals the batch-1 greedy reference
+    at temperature 0 (the expert layer's outputs do not depend on batch
+    composition)."""
     from repro.configs.base import ServeConfig
     from repro.serve import Engine
     cfg = get_model_config("trinity-mini", smoke=True)
@@ -154,8 +155,8 @@ def test_trinity_serves_on_the_normal_path():
     params = model.init(RNG)
     base = dict(max_batch=2, max_new_tokens=5, kv_cache_len=128)
 
-    def out(eng, reqs, **kw):
-        return {r.rid: list(r.out_tokens) for r in eng.run(reqs, **kw)}
+    def out(eng, reqs):
+        return {r.rid: list(r.out_tokens) for r in eng.run(reqs)}
 
     paged = Engine(model, params, cfg,
                    ServeConfig(**base, prefill_chunk=16, block_size=8),
@@ -167,7 +168,6 @@ def test_trinity_serves_on_the_normal_path():
     assert paged.moe_stats["ticks"] > 0 and paged.moe_stats["moe_rows"] > 0
     stripe = Engine(model, params, cfg, ServeConfig(**base), eos_id=-1)
     assert out(stripe, _trinity_requests(mixed)) == got
-    gang = Engine(model, params, cfg, ServeConfig(**base), eos_id=-1)
-    same = [8] * 5
-    assert out(paged, _trinity_requests(same)) == \
-        out(gang, _trinity_requests(same), scheduler="gang")
+    same = _trinity_requests([8] * 5)
+    want = greedy_reference(model, params, [r.prompt for r in same], 5)
+    assert out(paged, same) == dict(enumerate(want))

@@ -1,6 +1,7 @@
 """Persistent-slot continuous batching: temperature-0 equivalence with
-gang scheduling, mid-decode slot refill, WFQ slot shares, single-compile
-decode, fused-mediation bit-equivalence, and admission accounting."""
+the batch-1 greedy reference, mid-decode slot refill, WFQ slot shares,
+single-compile decode, fused-mediation bit-equivalence, and admission
+accounting."""
 
 import jax
 import jax.numpy as jnp
@@ -8,7 +9,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_model_config
-from repro.configs.base import DataplaneConfig, ModelConfig, ServeConfig
+from repro.configs.base import DataplaneConfig, ServeConfig
 from repro.core import Dataplane
 from repro.core import techniques as tech
 from repro.core import telemetry as tl
@@ -43,24 +44,24 @@ def _requests(lengths, tenants=None, max_new=16):
 
 
 # ---------------------------------------------------------------------------
-# scheduler equivalence + slot lifecycle
+# reference equivalence + slot lifecycle
 # ---------------------------------------------------------------------------
 
-def test_continuous_matches_gang_temp0(smoke_model):
-    """At temperature 0 continuous slots and gang scheduling emit the same
-    tokens.  Prompt lengths sit on a bucket boundary so the gang path's
-    left-padding (which perturbs logits for unaligned lengths — a legacy
-    gang property) is empty on both sides."""
+@pytest.mark.parametrize("block_size", [0, 8])
+def test_continuous_matches_reference_temp0(smoke_model, greedy_reference,
+                                            block_size):
+    """At temperature 0 the engine — five requests through two slots, on
+    the stripe and on the block pool — emits each request's tokens of a
+    plain batch-1 prefill-then-decode loop over its prompt alone."""
     cfg, model, params = smoke_model
-    sc = ServeConfig(max_batch=2, max_new_tokens=5, kv_cache_len=64)
-    cont = Engine(model, params, cfg, sc, eos_id=-1)
-    gang = Engine(model, params, cfg, sc, eos_id=-1)
-    out_c = {r.rid: r.out_tokens
-             for r in cont.run(_requests([8] * 5), scheduler="continuous")}
-    out_g = {r.rid: r.out_tokens
-             for r in gang.run(_requests([8] * 5), scheduler="gang")}
-    assert out_c == out_g
-    assert all(len(o) == 5 for o in out_c.values())
+    sc = ServeConfig(max_batch=2, max_new_tokens=5, kv_cache_len=64,
+                     block_size=block_size)
+    eng = Engine(model, params, cfg, sc, eos_id=-1)
+    reqs = _requests([8] * 5)
+    out = {r.rid: r.out_tokens for r in eng.run(reqs)}
+    want = greedy_reference(model, params, [r.prompt for r in reqs], 5)
+    assert out == dict(enumerate(want))
+    assert all(len(o) == 5 for o in out.values())
 
 
 def test_mid_decode_refill_tokens_independent_of_coresidents(smoke_model):
@@ -83,17 +84,14 @@ def test_mid_decode_refill_tokens_independent_of_coresidents(smoke_model):
 
 def test_single_decode_compilation_across_varied_stream(smoke_model):
     """One engine, one decode-step compile, regardless of the request
-    mix — while the gang baseline recompiles per distinct batch shape."""
+    mix."""
     cfg, model, params = smoke_model
     lengths = [4, 9, 17, 6, 12, 20, 5, 10]      # buckets 8 / 16 / 32
     sc = ServeConfig(max_batch=2, max_new_tokens=4, kv_cache_len=64)
     cont = Engine(model, params, cfg, sc, eos_id=-1)
-    cont.run(_requests(lengths), scheduler="continuous")
+    cont.run(_requests(lengths))
     assert cont._step_slots._cache_size() == 1
     assert cont.decode_compile_count() == 1
-    gang = Engine(model, params, cfg, sc, eos_id=-1)
-    gang.run(_requests(lengths), scheduler="gang")
-    assert gang.decode_compile_count() >= 2
 
 
 def test_wfq_slot_occupancy_proportional_to_weights(smoke_model):
@@ -170,59 +168,52 @@ def test_max_slots_per_tenant_caps_occupancy(smoke_model):
 # admission accounting (satellite regressions)
 # ---------------------------------------------------------------------------
 
-def _bucket_engine(rates, burst=1.0, max_batch=1, scale=4.0):
+def _bucket_engine(smoke_model, rates, burst=1.0, max_batch=1, scale=4.0):
+    cfg, model, params = smoke_model
     dp = Dataplane(DataplaneConfig(mode="cord"),
                    tenants=tuple(["default"] + list(rates)),
                    policies=[TelemetryPolicy(),
                              QoSPolicy(rates=rates, burst=burst)])
-    model = object()                     # _admit_batch never runs the model
-    return Engine(model, {}, ModelConfig(),
-                  ServeConfig(max_batch=max_batch,
-                              admission_token_scale=scale), dp=dp, eos_id=-1)
+    return Engine(model, params, cfg,
+                  ServeConfig(max_batch=max_batch, max_new_tokens=2,
+                              kv_cache_len=64, admission_token_scale=scale),
+                  dp=dp, eos_id=-1)
 
 
-def test_admit_batch_counts_bucket_deferral_behind_full_batch():
-    """Regression: a bucket-starved request sitting behind an already-full
-    batch must still be counted as deferred (the old ``len(admitted) < B``
-    guard masked it)."""
-    eng = _bucket_engine({"slow": 0.1}, burst=1.0, max_batch=1, scale=1.0)
-    eng._buckets["slow"].tokens = 0.0    # starved even after one refill
-    fast = Request(rid=0, prompt=np.arange(4, dtype=np.int32))
-    slow = Request(rid=1, prompt=np.arange(4, dtype=np.int32), tenant="slow")
-    admitted, deferred = eng._admit_batch([fast, slow])
-    assert admitted == [fast] and deferred == [slow]
-    assert eng.tenant_stats["slow"]["deferrals"] == 1
-
-
-def test_admission_charges_prompt_tokens():
+def test_admission_charges_prompt_tokens(smoke_model):
     """The host bucket debits len(prompt) per admission (scaled bucket),
     matching the traced bucket's byte-proportional debits."""
-    eng = _bucket_engine({"t": 1.0}, burst=4.0, max_batch=4, scale=4.0)
+    eng = _bucket_engine(smoke_model, {"t": 1.0}, burst=4.0, max_batch=4,
+                         scale=4.0)
     bucket = eng._buckets["t"]
     assert bucket.burst == 16.0 and bucket.rate == 4.0   # scaled by 4
-    r6 = Request(rid=0, prompt=np.arange(6, dtype=np.int32), tenant="t")
-    admitted, _ = eng._admit_batch([r6])
-    assert admitted == [r6]
-    assert bucket.tokens == 16.0 - 6.0   # refill capped at burst, then -6
+    r6 = Request(rid=0, prompt=np.arange(6, dtype=np.int32), tenant="t",
+                 max_new_tokens=2)
+    (done,) = eng.run([r6])
+    assert done is r6 and len(r6.out_tokens) == 2
+    # one scheduling round: refill capped at burst, then -6
+    assert bucket.tokens == 16.0 - 6.0
     assert bucket.can_take(10.0) and not bucket.can_take(10.1)
 
 
-def test_admission_cost_clamped_to_burst():
+def test_admission_cost_clamped_to_burst(smoke_model):
     """A prompt longer than the bucket can ever hold drains a full bucket
     instead of being permanently inadmissible (no 10k-round starvation
     spin)."""
-    eng = _bucket_engine({"t": 1.0}, burst=1.0, max_batch=2, scale=4.0)
-    big = Request(rid=0, prompt=np.arange(20, dtype=np.int32), tenant="t")
-    admitted, deferred = eng._admit_batch([big])
-    assert admitted == [big] and not deferred
+    eng = _bucket_engine(smoke_model, {"t": 1.0}, burst=1.0, max_batch=2,
+                         scale=4.0)
+    big = Request(rid=0, prompt=np.arange(20, dtype=np.int32), tenant="t",
+                  max_new_tokens=2)
+    (done,) = eng.run([big])
+    assert done is big and len(big.out_tokens) == 2
     assert eng._buckets["t"].tokens == 0.0       # burst 4 fully drained
     assert eng.tenant_stats["t"]["deferrals"] == 0
 
 
 def test_continuous_counts_deferrals_behind_occupied_slots(smoke_model):
     """A bucket-starved tenant waiting while every slot is occupied still
-    accrues deferrals (the continuous-path analogue of the _admit_batch
-    full-batch masking fix)."""
+    accrues deferrals: starvation is counted per scheduling round, not
+    only when a slot is free."""
     cfg, model, params = smoke_model
     dp = Dataplane(
         DataplaneConfig(mode="cord"), tenants=("default", "slow"),
@@ -272,13 +263,6 @@ def test_duplicate_rids_and_prompts_are_servable(smoke_model):
     done = eng.run(dup)
     assert len(done) == 3 and all(r.done for r in done)
     assert all(len(r.out_tokens) == 3 for r in done)
-
-
-def test_unknown_scheduler_raises(smoke_model):
-    cfg, model, params = smoke_model
-    eng = Engine(model, params, cfg, ServeConfig(max_batch=1), eos_id=-1)
-    with pytest.raises(ValueError, match="unknown scheduler"):
-        eng.run([], scheduler="continous")
 
 
 def test_host_bucket_from_policy_scaling():

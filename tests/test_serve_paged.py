@@ -148,16 +148,13 @@ def test_paged_decode_matches_stripe_bitwise(smoke_model):
 
 def test_paged_admits_prompt_longer_than_stripe(smoke_model):
     """Slot count decouples from context length: a prompt no fixed stripe
-    can hold is admissible while free blocks exist; the stripe and gang
-    paths reject it with a clear submit-time ServeError."""
+    can hold is admissible while free blocks exist; the stripe layout
+    rejects it with a clear submit-time ServeError."""
     cfg, model, params = smoke_model
     base = dict(max_batch=2, max_new_tokens=8, kv_cache_len=56)
     stripe = Engine(model, params, cfg, ServeConfig(**base), eos_id=-1)
     with pytest.raises(ServeError, match="cache positions"):
         stripe.run(_requests([80]))
-    gang = Engine(model, params, cfg, ServeConfig(**base), eos_id=-1)
-    with pytest.raises(ServeError, match="gang request"):
-        gang.run(_requests([80]), scheduler="gang")
     paged = Engine(model, params, cfg,
                    ServeConfig(**base, block_size=8, n_blocks=24), eos_id=-1)
     (done,) = paged.run(_requests([80]))
@@ -191,6 +188,34 @@ def test_pool_pressure_preempts_and_resumes_exact(smoke_model):
     assert ctrs[i, tl.CTR_PREEMPTIONS] == rep["preemptions"]
     assert ctrs[i, tl.CTR_RESTORES] == rep["restores"]
     assert tight._alloc.free_blocks == 3       # every block returned
+
+
+def test_preemption_lands_in_saved_timeline(smoke_model, tmp_path):
+    """Preempt/restore is visible in a saved engine timeline: a 9-block
+    pool fits one request's whole lifetime (the submit bound) but not two
+    co-residents' decode growth (5 blocks each by the end), and the
+    artifact carries the cumulative counters, the ``preempt_s`` /
+    ``restore_s`` rates and the ``free_blocks`` gauge."""
+    from repro.core.obs import CounterTimeline
+
+    cfg, model, params = smoke_model
+    timeline = CounterTimeline(source="test-serve/preempt")
+    eng = Engine(model, params, cfg,
+                 ServeConfig(max_batch=2, max_new_tokens=32, kv_cache_len=64,
+                             block_size=8, n_blocks=9),
+                 eos_id=-1, obs=timeline)
+    done = eng.run(_requests([8, 8], max_new=32))
+    assert all(len(r.out_tokens) == 32 for r in done)
+    rep = eng.tenant_report()["default"]
+    assert rep["preemptions"] > 0 and rep["restores"] > 0, rep
+    doc = CounterTimeline.load(
+        timeline.save(str(tmp_path / "serve_timeline.json")))
+    assert doc["samples"], "engine timeline captured no ticks"
+    last = doc["samples"][-1]
+    assert last["tenants"]["default"]["preemptions"] == rep["preemptions"]
+    assert last["tenants"]["default"]["restores"] == rep["restores"]
+    assert {"preempt_s", "restore_s"} <= set(doc["rate_fields"])
+    assert "free_blocks" in last["gauges"]
 
 
 def test_slot_budget_preempts_mid_run_exact(smoke_model):

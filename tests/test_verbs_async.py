@@ -273,24 +273,26 @@ def test_msg_bytes_must_match_dtype_itemsize():
 
 class _EOSModel:
     """Stub model whose argmax token is always ``eos`` and which counts
-    decode steps host-side."""
+    calls into its slot decode step (the engine's first decode tick would
+    make one)."""
 
     def __init__(self, vocab=8, eos=1):
         self.vocab, self.eos = vocab, eos
         self.decode_calls = 0
 
     def init_cache(self, batch, cache_len):
-        return {"len": jnp.zeros((batch,), jnp.int32)}
+        # batch on axis 1, as in every family's cache
+        return {"len": jnp.zeros((1, batch), jnp.int32)}
 
     def _logits(self, b, s):
         return jnp.zeros((b, s, self.vocab)) \
             .at[:, :, self.eos].set(10.0)
 
-    def prefill(self, params, batch, cache, dp=None):
+    def prefill(self, params, batch, cache, dp=None, last_pos=None):
         toks = batch["tokens"]
         return self._logits(toks.shape[0], toks.shape[1]), cache
 
-    def decode_step(self, params, tok, cache, pos, dp=None):
+    def decode_step_slots(self, params, tok, cache, pos, dp=None):
         self.decode_calls += 1
         return self._logits(tok.shape[0], 1), cache
 

@@ -36,7 +36,7 @@ _SKIP_SCHEMES = ("http://", "https://", "mailto:", "#")
 INLINE_CODE_RE = re.compile(r"`([^`\n]+)`")
 FILE_REF_RE = re.compile(r"^[\w./-]+\.py$")
 CALL_REF_RE = re.compile(r"^[A-Za-z_][\w.]*\([^()]*\)$")
-_PY_DIRS = ("src", "benchmarks", "tools", "examples", "tests")
+_PY_DIRS = ("src", "bench", "benchmarks", "tools", "examples", "tests")
 
 
 def _md_files() -> list[pathlib.Path]:
